@@ -6,6 +6,7 @@ import json
 from typing import Dict
 
 from repro.roofline import V5E, advise_allreduce, analytic_time
+from repro.util import enable_compile_cache
 
 
 def main(quick: bool = False) -> Dict:
@@ -36,5 +37,6 @@ def main(quick: bool = False) -> Dict:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     json.dump(main(), open("experiments/advisor_validation.json", "w"),
               indent=1)

@@ -37,6 +37,7 @@ from repro.api import Experiment
 from repro.core import (PolicyConfig, ROUTE_LEGACY, ROUTE_SDN, SPEC_OFF,
                         SPEC_ON)
 from repro.scenarios import get_scenario
+from repro.util import enable_compile_cache
 
 
 def check_regression(report: dict, baseline_path: str,
@@ -155,4 +156,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.exit(main())

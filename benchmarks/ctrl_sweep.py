@@ -37,6 +37,7 @@ except ImportError:
 from repro.api import Experiment
 from repro.core import (CtrlPlaneConfig, INSTALL_PROACTIVE, PolicyConfig,
                         ROUTE_LEGACY, ROUTE_SDN)
+from repro.util import enable_compile_cache
 
 
 def main(argv=None):
@@ -130,4 +131,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -42,6 +42,7 @@ from repro.core.engine import make_consts
 from repro.core.policies import as_policy_arrays
 from repro.scenarios import get_scenario
 from repro.scenarios.sweep import policy_arrays
+from repro.util import enable_compile_cache
 
 # tier -> (registered scenario, default policy-batch width, fleet widths).
 # All sizes come from the registry so the profile and the bit-identity
@@ -254,4 +255,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.exit(main())

@@ -35,6 +35,7 @@ from repro.api import Experiment
 from repro.core import (PolicyConfig, ROUTE_LEGACY, ROUTE_SDN, paper_jobs,
                         paper_setup)
 from repro.scenarios.failures import failure_injector
+from repro.util import enable_compile_cache
 
 
 def main(argv=None):
@@ -130,4 +131,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -16,6 +16,7 @@ import numpy as np
 
 from repro.api import Experiment
 from repro.core import PolicyConfig, ROUTE_LEGACY, ROUTE_SDN, paper_setup
+from repro.util import enable_compile_cache
 
 PAPER = {"transmission": 41.0, "completion": 24.0, "energy": 22.0}
 
@@ -104,4 +105,5 @@ def main(quick: bool = False) -> Dict:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     json.dump(main(), open("experiments/fig11_13.json", "w"), indent=1)

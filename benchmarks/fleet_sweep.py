@@ -36,6 +36,7 @@ except ImportError:
 
 from repro.api import Experiment
 from repro.scenarios.failures import failure_injector
+from repro.util import enable_compile_cache
 
 SCENARIO = "paper-fabric"
 ROUTINGS = (("legacy", 0), ("sdn", 1))
@@ -161,4 +162,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.exit(main())

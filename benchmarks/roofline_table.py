@@ -7,6 +7,7 @@ import os
 from typing import Dict, List
 
 from repro.configs import ARCH_IDS, SHAPES
+from repro.util import enable_compile_cache
 
 
 def load(dirpath: str = "experiments/dryrun") -> List[Dict]:
@@ -59,4 +60,5 @@ def main(dirpath: str = "experiments/dryrun") -> Dict:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -112,4 +112,6 @@ def main(argv=None):  # jaxcheck: disable=naked-timer
 
 
 if __name__ == "__main__":
+    from repro.util import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

@@ -28,6 +28,7 @@ from repro.api import Experiment
 from repro.core import (PLACE_LEAST_USED, PLACE_RANDOM, PLACE_ROUND_ROBIN,
                         PolicyConfig)
 from repro.scenarios import get_scenario, list_scenarios
+from repro.util import enable_compile_cache
 
 PLACEMENTS = (
     ("least-used", PLACE_LEAST_USED),
@@ -113,4 +114,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -17,6 +17,7 @@ from repro.api import Experiment, PolicyConfig, runners
 from repro.core import ROUTE_LEGACY, ROUTE_SDN, paper_setup
 from repro.core.engine import make_consts
 from repro.core.policies import as_policy_arrays
+from repro.util import enable_compile_cache
 
 
 def single_run_events_per_sec(setup) -> Dict[str, float]:
@@ -77,4 +78,5 @@ def main(quick: bool = False) -> Dict:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     json.dump(main(), open("experiments/sim_throughput.json", "w"), indent=1)

@@ -35,6 +35,7 @@ from repro.api import Experiment
 from repro.core import PolicyConfig, ROUTE_LEGACY, ROUTE_SDN
 from repro.scenarios import get_scenario
 from repro.scenarios.registry import stream_arrivals
+from repro.util import enable_compile_cache
 
 SCENARIO = "leaf-spine"
 POLICIES = [
@@ -160,4 +161,5 @@ def main(argv=None) -> int:  # jaxcheck: disable=naked-timer
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.exit(main())

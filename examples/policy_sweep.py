@@ -16,6 +16,7 @@ from repro.api import Experiment, PolicyConfig
 from repro.core import (JOBSEL_FCFS, JOBSEL_SJF, PLACE_LEAST_USED,
                         PLACE_RANDOM, ROUTE_LEGACY, ROUTE_SDN,
                         TRAFFIC_FAIRSHARE, TRAFFIC_WATERFILL, paper_setup)
+from repro.util import enable_compile_cache
 
 
 def main():
@@ -60,4 +61,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

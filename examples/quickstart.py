@@ -8,6 +8,9 @@ import numpy as np
 from repro.api import Experiment, PolicyConfig
 from repro.core import ROUTE_LEGACY, ROUTE_SDN
 from repro.scenarios import get_scenario
+from repro.util import enable_compile_cache
+
+enable_compile_cache()
 
 # --- 1. BigDataSDNSim: SDN vs legacy on the paper's fat-tree (Tables 2-3).
 # One declarative experiment; .run() compiles once and returns the grid.

@@ -12,6 +12,9 @@ import numpy as np
 from repro.api import Experiment
 from repro.core import PolicyConfig, ROUTE_LEGACY, ROUTE_SDN
 from repro.scenarios import get_scenario, list_scenarios
+from repro.util import enable_compile_cache
+
+enable_compile_cache()
 
 names = sys.argv[1:] or list_scenarios()
 scens = []

@@ -13,6 +13,7 @@ import numpy as np
 
 sys.path.insert(0, ".")
 from benchmarks.fig11_13_usecase import main as bench_main  # noqa: E402
+from repro.util import enable_compile_cache  # noqa: E402
 
 
 def run(full: bool):
@@ -39,6 +40,7 @@ def run(full: bool):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     run(ap.parse_args().full)

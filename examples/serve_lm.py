@@ -11,6 +11,7 @@ import numpy as np
 from repro.configs import get_smoke_config
 from repro.models import get_model
 from repro.serve import Request, ServeLoop
+from repro.util import enable_compile_cache
 
 
 def main():
@@ -46,4 +47,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

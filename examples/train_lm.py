@@ -21,6 +21,7 @@ from repro.models import get_model
 from repro.models.layers import ModelConfig
 from repro.train import AdamWConfig, make_train_step
 from repro.train import init as opt_init
+from repro.util import enable_compile_cache
 
 PRESETS = {
     "tiny": ModelConfig(name="tiny-2m", n_layers=2, d_model=128, n_heads=4,
@@ -79,4 +80,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -1,7 +1,1 @@
-"""BigDataSDNSim reproduction as a jax tensor program.
-
-Importing any ``repro`` submodule first installs the jax API-compat shims
-(``repro.compat``) so the codebase runs unmodified on both jax 0.4.x and
-current jax.
-"""
-from . import compat  # noqa: F401  (side effect: jax API shims)
+"""BigDataSDNSim reproduction as a jax tensor program."""

@@ -46,7 +46,7 @@ class ProgramTrace:
     axes: Dict[str, int]        # {"packets": n, "tasks": n, "jobs": n, ...}
     sig: Optional[Tuple[int, ...]] = None   # fleet static signature
     donated: int = 0            # trailing flat invars that form the
-    #                             donated state arg on donating backends
+    #                             donated state arg
     expect_loop: bool = True    # engine programs must contain a while
     expect_loop_cond: bool = True  # ... whose body keeps >=1 lax.cond
 
@@ -169,8 +169,8 @@ def check_donation(trace: ProgramTrace) -> List[Finding]:
     """Aval feasibility of buffer donation: every donated input must find
     a distinct output aval of the same shape/dtype to alias into,
     otherwise XLA silently keeps both copies and the donation is a lie.
-    (The backend policy itself — donate off-CPU, never on CPU — is
-    checked once per run by ``check_donation_policy``.)"""
+    (The policy itself — donate the state argument — is checked once
+    per run by ``check_donation_policy``.)"""
     if trace.donated <= 0:
         return []
     jaxpr = trace.closed.jaxpr
@@ -194,21 +194,18 @@ def check_donation(trace: ProgramTrace) -> List[Finding]:
     return []
 
 
-def check_donation_policy(donation_argnums) -> List[Finding]:
+def check_donation_policy(donate_argnums) -> List[Finding]:
     """The single-source-of-truth donation policy used by the runner
-    cache and the fleet chunk: argument 2 (the t=0 state) is donated on
-    every backend EXCEPT cpu, where donation is unsupported and warns."""
-    out = []
-    for backend, expect in (("cpu", ()), ("gpu", (2,)), ("tpu", (2,))):
-        got = tuple(donation_argnums(backend))
-        if got != expect:
-            out.append(Finding(
-                rule="donation",
-                where=f"runners.donation_argnums({backend!r})",
-                message=f"expected donate_argnums {expect} on {backend}, "
-                        f"got {got}",
-                key=f"donation:policy:{backend}"))
-    return out
+    cache and the fleet chunk: argument 2 (the t=0 state) is donated, on
+    every backend alike."""
+    got = tuple(donate_argnums)
+    if got == (2,):
+        return []
+    return [Finding(
+        rule="donation",
+        where="runners.DONATE_ARGNUMS",
+        message=f"expected donate_argnums (2,), got {got}",
+        key="donation:policy")]
 
 
 # --- jaxcheck:carry-stability ---------------------------------------------

@@ -176,31 +176,40 @@ def _chunk_program(meta: SimMeta, sig: Tuple[int, ...], chunk_steps: int,
             fn = jax.shard_map(counted, mesh=mesh,
                                in_specs=(P(), P("fleet"), P("fleet")),
                                out_specs=P("fleet"), check_vma=False)
-        # donating the carry lets XLA alias it through the while loop;
-        # the shared policy skips the CPU backend (jaxcheck:donation)
-        return jax.jit(fn, donate_argnums=runners.donation_argnums())
+        # donating the carry lets XLA alias it through the while loop
+        # (jaxcheck:donation); the caller never reads a carry it passed in
+        return jax.jit(fn, donate_argnums=runners.DONATE_ARGNUMS)
 
     return runners.get_cached_program(key, build)
 
 
 def _refill_program(meta: SimMeta, width: int) -> Callable:
-    """Cached jitted refill: ``(mask, carry0, carry) -> carry`` with
-    refilled lanes reset to the t=0 carry.  Eager ``tree_select`` is ~70
+    """Cached jitted refill: ``(mask, consts, carry) -> carry`` with
+    refilled lanes reset to the t=0 carry.  The t=0 carry is rebuilt from
+    ``consts`` inside the program rather than kept from cohort start: the
+    chunk donates every carry it is given, so a kept copy would share
+    buffers the first chunk call deletes.  Eager ``tree_select`` is ~70
     per-leaf dispatches per chunk boundary — a large fraction of host time
     on fast tiers."""
     key = ("fleet-refill", meta, width)
     return runners.get_cached_program(
-        key, lambda: jax.jit(tree_select))
+        key, lambda: jax.jit(lambda mask, c, carry: tree_select(
+            mask, init_fleet_carry(c, meta, width), carry)))
 
 
 def _init_program(meta: SimMeta, width: int) -> Callable:
-    """Cached jitted cohort initializer: ``consts -> t=0 carry``.  Eager
+    """Cached jitted cohort initializer: ``(consts, pad) -> t=0 carry``
+    with the ``pad`` lanes (``[W]`` bool) marked done.  Eager
     ``init_fleet_carry`` dispatches ~35 broadcast ops plus the endpoint
     cache per cohort (~6 ms on the small tier — comparable to a whole
     chunk); jitted it is one cached executable per (meta, width)."""
     key = ("fleet-init", meta, width)
-    return runners.get_cached_program(
-        key, lambda: jax.jit(lambda c: init_fleet_carry(c, meta, width)))
+
+    def init(c, pad):
+        s0, cache0, done0 = init_fleet_carry(c, meta, width)
+        return s0, cache0, done0 | pad
+
+    return runners.get_cached_program(key, lambda: jax.jit(init))
 
 
 def _lane_policies(pol_np: Dict[str, np.ndarray],
@@ -273,10 +282,8 @@ def run_fleet(exp, width: int = 32, chunk_steps: int = 32,
             stats.width = max(stats.width, W)
 
             chunk = _chunk_program(meta, sig, chunk_steps, W, n_dev)
-            carry0 = _init_program(meta, W)(consts_s)
-            s0, cache0, done0 = carry0
-            carry = (s0, cache0,
-                     jnp.asarray(np.asarray(done0) | sched.pad_mask()))
+            carry = _init_program(meta, W)(consts_s,
+                                           jnp.asarray(sched.pad_mask()))
 
             # hard backstop: every member can run at most max_steps events
             max_chunks = ((len(order) + W)
@@ -315,7 +322,7 @@ def run_fleet(exp, width: int = 32, chunk_steps: int = 32,
                     # where refilled: back to the t=0 carry (done leaf
                     # included — a sim finished at t=0 stays frozen and
                     # retires with its s0 state, exactly like serial)
-                    carry = _refill_program(meta, W)(mask, carry0, carry)
+                    carry = _refill_program(meta, W)(mask, consts_s, carry)
                     pol_lane = _lane_policies(pol_np, sched)
 
     states = state_cls(*out)   # the serial runner's [S, P, ...] grid

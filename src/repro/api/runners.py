@@ -28,8 +28,8 @@ The t=0 state is built by a separate (cached, jitted) initializer and
 passed into the main program as a DONATED argument (DESIGN.md §8): XLA
 aliases the init buffers straight into the while-loop carry and the final
 ``SimState`` outputs instead of materializing a second copy per replica.
-(Buffer donation is a no-op on the CPU backend, so it is only requested
-elsewhere.)
+Every backend donates, the CPU included, so the CPU tests exercise the
+same buffer lifetimes as the TPU.
 """
 from __future__ import annotations
 
@@ -102,15 +102,13 @@ def get_runner(meta: SimMeta, kind: str) -> Callable:
     return get_cached_program((meta, kind), lambda: _build(meta, kind))
 
 
-def donation_argnums(backend: str | None = None) -> Tuple[int, ...]:
-    """The donation policy shared by every jitted engine program (here and
-    ``api.fleet._chunk_program``): argument 2 — the t=0 state / chunk
-    carry — is donated so XLA aliases the init buffers straight into the
-    while-loop carry and final outputs, EXCEPT on the CPU backend, which
-    has no donation support and would warn on every call.  Audited by the
-    static analyzer (jaxcheck:donation, DESIGN.md §12)."""
-    backend = backend or jax.default_backend()
-    return () if backend == "cpu" else (2,)
+# The donation policy shared by every jitted engine program (here and
+# ``api.fleet._chunk_program``), on every backend alike: argument 2 — the
+# t=0 state / chunk carry — is donated so XLA aliases the init buffers
+# straight into the while-loop carry and final outputs.  A donated buffer
+# is deleted by the call, so no caller may read it again.  Audited by the
+# static analyzer (jaxcheck:donation, DESIGN.md §12).
+DONATE_ARGNUMS: Tuple[int, ...] = (2,)
 
 
 def traced_jaxpr(meta: SimMeta, kind: str, consts, pols):
@@ -168,7 +166,7 @@ def _make_fn(meta: SimMeta, kind: str, counted: bool = True):
 
 def _build(meta: SimMeta, kind: str) -> Callable:
     fn, init = _make_fn(meta, kind)
-    run_jit = jax.jit(fn, donate_argnums=donation_argnums())
+    run_jit = jax.jit(fn, donate_argnums=DONATE_ARGNUMS)
     init_jit = jax.jit(init)
 
     def call(consts, pols):

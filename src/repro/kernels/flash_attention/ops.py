@@ -13,17 +13,12 @@ import jax.numpy as jnp
 from .kernel import flash_attention_bhsd
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @partial(jax.jit, static_argnames=("causal", "q_offset", "bq", "bk",
                                    "interpret"))
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     causal: bool = True, q_offset: int = 0,
                     bq: int = 128, bk: int = 128,
-                    interpret: bool | None = None) -> jnp.ndarray:
-    interpret = _default_interpret() if interpret is None else interpret
+                    interpret: bool = False) -> jnp.ndarray:
     b, sq, h, dh = q.shape
     skv, kv = k.shape[1], k.shape[2]
     g = h // kv

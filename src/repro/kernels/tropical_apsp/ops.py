@@ -1,7 +1,7 @@
 """Jit'd public wrapper: APSP via Pallas min-plus squaring.
 
-On CPU (this container) the kernel runs in interpret mode; on TPU set
-``interpret=False`` (default picks by backend).
+Compiles for the TPU by default; pass ``interpret=True`` to run the
+kernel through the Pallas interpreter (how the CPU tests run it).
 """
 from __future__ import annotations
 
@@ -14,18 +14,13 @@ import numpy as np
 from .kernel import minplus_matmul
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @partial(jax.jit, static_argnames=("steps", "interpret", "block"))
 def apsp(adj: jnp.ndarray, *, steps: int | None = None,
-         interpret: bool | None = None, block: int = 128) -> jnp.ndarray:
+         interpret: bool = False, block: int = 128) -> jnp.ndarray:
     """Tropical-semiring all-pairs shortest paths.
 
     adj: [n, n] edge weights (inf = no edge, 0 diagonal).
     """
-    interpret = _default_interpret() if interpret is None else interpret
     n = adj.shape[0]
     steps = steps if steps is not None else max(1, int(np.ceil(np.log2(n))))
     d = adj.astype(jnp.float32)

@@ -75,11 +75,9 @@ def test_clean_program_passes_every_checker():
 
 
 def test_donation_policy_checker_and_falsifiability():
-    assert check_donation_policy(runners.donation_argnums) == []
-    # a policy that donates on cpu must be flagged
-    bad = lambda backend=None: (2,)                     # noqa: E731
-    assert any(f.rule == "donation"
-               for f in check_donation_policy(bad))
+    assert check_donation_policy(runners.DONATE_ARGNUMS) == []
+    # a policy that does not donate the state argument must be flagged
+    assert any(f.rule == "donation" for f in check_donation_policy(()))
 
 
 # ---------------------------------------------------------------------------
@@ -235,7 +233,7 @@ def test_quick_sweep_and_committed_budget_clean():
     derived rows match the committed PRIM_BUDGET.json exactly."""
     traces = list(iter_traces(["paper-fabric"], sigs=static_sigs()[:1]))
     findings, programs = analyze(traces)
-    findings += check_donation_policy(runners.donation_argnums)
+    findings += check_donation_policy(runners.DONATE_ARGNUMS)
     assert [f.render() for f in findings] == []
     baseline = load_ledger(ROOT / "experiments" / "PRIM_BUDGET.json")
     assert baseline is not None, "committed PRIM_BUDGET.json missing"
@@ -254,7 +252,7 @@ def test_full_registry_sweep_zero_unallowlisted_findings():
     """Every registry scenario x kind x static signature against the
     committed ledger: nothing unallowlisted may fire."""
     findings, programs = analyze(list(iter_traces()))
-    findings += check_donation_policy(runners.donation_argnums)
+    findings += check_donation_policy(runners.DONATE_ARGNUMS)
     baseline = load_ledger(ROOT / "experiments" / "PRIM_BUDGET.json")
     diff, _ = diff_ledger(programs, baseline, full_sweep=True)
     errors = [f for f in findings + diff if f.severity == "error"]

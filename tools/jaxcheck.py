@@ -124,7 +124,7 @@ def main(argv=None) -> int:
                 a, b = clean_trace(), clean_trace(n_packets=96)
                 traces += [a, b]
         findings, programs = analyze(traces)
-        findings += check_donation_policy(runners.donation_argnums)
+        findings += check_donation_policy(runners.DONATE_ARGNUMS)
 
         baseline_path = args.baseline or (
             DEFAULT_BASELINE if args.update_baseline else None)
