@@ -23,6 +23,7 @@ from typing import Any, List, Mapping, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from ..core import policies as policy_mod
 from ..core.ctrlplane import CtrlPlaneConfig
@@ -202,24 +203,26 @@ class Experiment:
                             and degradation is None
                             and all(isinstance(i, str) for i in items)
                             else None)
-        self.scenarios: List[Tuple[str, SimSetup]] = _normalize(
-            scenarios, _build_scenario, "scenario")
-        if failures is not None:
-            self.scenarios = _cross_failures(self.scenarios, failures)
-        if degradation is not None:
-            self.scenarios = _cross_degradation(self.scenarios, degradation)
-        if ctrl is not None:
-            self.scenarios = _cross_ctrl(self.scenarios, ctrl)
-        pols = _normalize(
-            policies, lambda p: (_policy_label(p), p), "policy")
-        if seeds is not None:
-            seeds = list(seeds)
-            if not seeds:
-                raise ValueError("seeds must be non-empty when given")
-            pols = [(f"{name}/s{seed}" if len(seeds) > 1 else name,
-                     _with_seed(pol, seed))
-                    for name, pol in pols for seed in seeds]
-        self.policies: List[Tuple[str, Any]] = pols
+        with TraceAnnotation("repro.front.grid"):
+            self.scenarios: List[Tuple[str, SimSetup]] = _normalize(
+                scenarios, _build_scenario, "scenario")
+            if failures is not None:
+                self.scenarios = _cross_failures(self.scenarios, failures)
+            if degradation is not None:
+                self.scenarios = _cross_degradation(self.scenarios,
+                                                    degradation)
+            if ctrl is not None:
+                self.scenarios = _cross_ctrl(self.scenarios, ctrl)
+            pols = _normalize(
+                policies, lambda p: (_policy_label(p), p), "policy")
+            if seeds is not None:
+                seeds = list(seeds)
+                if not seeds:
+                    raise ValueError("seeds must be non-empty when given")
+                pols = [(f"{name}/s{seed}" if len(seeds) > 1 else name,
+                         _with_seed(pol, seed))
+                        for name, pol in pols for seed in seeds]
+            self.policies: List[Tuple[str, Any]] = pols
         # the grid is immutable after __init__, so packing/stacking happens
         # once: repeated .run() calls are pack-free as well as trace-free
         self._built = None
@@ -249,11 +252,12 @@ class Experiment:
                 return self._built
             global _CONSTS_BUILDS
             _CONSTS_BUILDS += 1
-            if len(self.scenarios) == 1:
-                self._built = make_consts(self.scenarios[0][1])
-            else:
-                from ..scenarios.sweep import pack_setups
-                self._built = pack_setups([s for _, s in self.scenarios])
+            with TraceAnnotation("repro.front.consts"):
+                if len(self.scenarios) == 1:
+                    self._built = make_consts(self.scenarios[0][1])
+                else:
+                    from ..scenarios.sweep import pack_setups
+                    self._built = pack_setups([s for _, s in self.scenarios])
             if key is not None:
                 _lru_put(_CONSTS_CACHE, key, self._built)
         return self._built
@@ -261,9 +265,10 @@ class Experiment:
     def policy_arrays(self):
         """Registry-ordered ``[P]``-shaped policy arrays (memoized)."""
         if self._pol_arrays is None:
-            stacked = [as_policy_arrays(p) for _, p in self.policies]
-            self._pol_arrays = {k: jnp.stack([s[k] for s in stacked])
-                                for k in stacked[0]}
+            with TraceAnnotation("repro.front.policies"):
+                stacked = [as_policy_arrays(p) for _, p in self.policies]
+                self._pol_arrays = {k: jnp.stack([s[k] for s in stacked])
+                                    for k in stacked[0]}
         return self._pol_arrays
 
     # -- execution ----------------------------------------------------------
@@ -273,16 +278,18 @@ class Experiment:
         S, P = len(self.scenarios), len(self.policies)
         consts, meta = self.build()
         pols = self.policy_arrays()
-        if S == 1 and P == 1:
-            pols = jax.tree_util.tree_map(lambda a: a[0], pols)
-            states = runners.get_runner(meta, "single")(consts, pols)
-            expand = lambda a: a[None, None]                  # noqa: E731
-        elif S == 1:
-            states = runners.get_runner(meta, "policy_batch")(consts, pols)
-            expand = lambda a: a[None]                        # noqa: E731
-        else:
-            states = runners.get_runner(meta, "grid")(consts, pols)
-            expand = None
+        with TraceAnnotation("repro.run.dispatch"):
+            if S == 1 and P == 1:
+                pols = jax.tree_util.tree_map(lambda a: a[0], pols)
+                states = runners.get_runner(meta, "single")(consts, pols)
+                expand = lambda a: a[None, None]              # noqa: E731
+            elif S == 1:
+                states = runners.get_runner(meta, "policy_batch")(consts,
+                                                                  pols)
+                expand = lambda a: a[None]                    # noqa: E731
+            else:
+                states = runners.get_runner(meta, "grid")(consts, pols)
+                expand = None
         if expand is not None:
             states = jax.tree_util.tree_map(expand, states)
         if S == 1:   # Results keeps a scenario axis on consts

@@ -40,6 +40,7 @@ from typing import Any, Callable, Dict, Hashable, List, Optional, Sequence, Tupl
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core.engine import init_fleet_carry, make_fleet_chunk, tree_select
 from ..core.simmeta import SimMeta
@@ -149,6 +150,32 @@ class FleetStats:
     refills: int = 0     # lanes recycled mid-cohort
     devices: int = 1     # fleet-mesh size (1 = no shard_map)
     width: int = 0       # lanes per cohort (after device round-up)
+    # every array run_fleet moves between host and device, and its bytes
+    # (shape x itemsize): done flags, retire fetches, masks and the numpy
+    # arguments a chunk, init or refill call uploads
+    d2h: int = 0
+    d2h_bytes: int = 0
+    h2d: int = 0
+    h2d_bytes: int = 0
+
+    def fetch(self, a) -> np.ndarray:
+        """``a`` on the host; a device array counts as one transfer."""
+        if isinstance(a, jax.Array):
+            self.d2h += 1
+            self.d2h_bytes += a.nbytes
+        return np.asarray(a)
+
+    def upload(self, a: np.ndarray) -> jax.Array:
+        """``a`` on the device, counted as one transfer."""
+        self.count_uploads(a)
+        return jnp.asarray(a)
+
+    def count_uploads(self, tree) -> None:
+        """Count the numpy leaves of ``tree``: a jitted call uploads each."""
+        for a in jax.tree_util.tree_leaves(tree):
+            if isinstance(a, np.ndarray):
+                self.h2d += 1
+                self.h2d_bytes += a.nbytes
 
 
 def _chunk_program(meta: SimMeta, sig: Tuple[int, ...], chunk_steps: int,
@@ -240,19 +267,22 @@ def run_fleet(exp, width: int = 32, chunk_steps: int = 32,
     S, P = len(exp.scenarios), len(exp.policies)
     consts, meta = exp.build()
     meta = SimMeta.coerce(meta)
-    pol_np = {k: np.asarray(v) for k, v in exp.policy_arrays().items()}
 
     n_dev = devices if devices is not None else jax.local_device_count()
     n_dev = max(1, min(n_dev, jax.local_device_count()))
 
-    # group the policy axis by static signature: one cohort per
-    # (scenario, sig) shares one specialized chunk program
-    groups: Dict[Tuple[int, ...], List[int]] = {}
-    for p in range(P):
-        sig = tuple(int(pol_np[f][p]) for f in STATIC_FIELDS)
-        groups.setdefault(sig, []).append(p)
-
     stats = FleetStats(sims=S * P, devices=n_dev)
+    policy_arrays = exp.policy_arrays()
+    with TraceAnnotation("repro.fleet.cohort"):
+        pol_np = {k: stats.fetch(v) for k, v in policy_arrays.items()}
+
+        # group the policy axis by static signature: one cohort per
+        # (scenario, sig) shares one specialized chunk program
+        groups: Dict[Tuple[int, ...], List[int]] = {}
+        for p in range(P):
+            sig = tuple(int(pol_np[f][p]) for f in STATIC_FIELDS)
+            groups.setdefault(sig, []).append(p)
+
     # final [S, P, ...] state grid, allocated once and written in place at
     # retire time (one vectorized row-gather per leaf per boundary — per-sim
     # tree copies cost ~leaves × sims tiny np ops and dominated the host
@@ -261,69 +291,81 @@ def run_fleet(exp, width: int = 32, chunk_steps: int = 32,
     state_cls = None
 
     for si in range(S):
-        if S == 1:
-            consts_s = consts
-        else:
-            from ..scenarios.sweep import slice_packed
-            consts_s = slice_packed(consts, si)
-        n_tasks = int(np.sum(np.asarray(consts_s.task_valid)))
-        n_pkts = int(np.sum(np.asarray(consts_s.pkt_valid)))
+        with TraceAnnotation("repro.fleet.cohort"):
+            if S == 1:
+                consts_s = consts
+            else:
+                from ..scenarios.sweep import slice_packed
+                consts_s = slice_packed(consts, si)
+            n_tasks = int(np.sum(stats.fetch(consts_s.task_valid)))
+            n_pkts = int(np.sum(stats.fetch(consts_s.pkt_valid)))
         sname = exp.scenario_names[si]
 
         for sig, members in groups.items():
-            gkey = (sname, sig)
-            order = sorted(members, key=lambda p: predictor.predict(
-                (sname, sig, exp.policy_names[p]), gkey, n_tasks, n_pkts))
-            W = min(width, len(order))
-            if n_dev > 1:
-                W = n_dev * math.ceil(W / n_dev)
-            sched = CohortSchedule(order, W)
-            stats.cohorts += 1
-            stats.width = max(stats.width, W)
+            with TraceAnnotation("repro.fleet.cohort"):
+                gkey = (sname, sig)
+                order = sorted(members, key=lambda p: predictor.predict(
+                    (sname, sig, exp.policy_names[p]), gkey, n_tasks,
+                    n_pkts))
+                W = min(width, len(order))
+                if n_dev > 1:
+                    W = n_dev * math.ceil(W / n_dev)
+                sched = CohortSchedule(order, W)
+                stats.cohorts += 1
+                stats.width = max(stats.width, W)
 
-            chunk = _chunk_program(meta, sig, chunk_steps, W, n_dev)
-            carry = _init_program(meta, W)(consts_s,
-                                           jnp.asarray(sched.pad_mask()))
+                chunk = _chunk_program(meta, sig, chunk_steps, W, n_dev)
+                carry = _init_program(meta, W)(
+                    consts_s, stats.upload(sched.pad_mask()))
 
-            # hard backstop: every member can run at most max_steps events
-            max_chunks = ((len(order) + W)
-                          * (meta.max_steps // chunk_steps + 2))
-            chunks = 0
-            pol_lane = _lane_policies(pol_np, sched)
+                # hard backstop: every member can run at most max_steps
+                # events
+                max_chunks = ((len(order) + W)
+                              * (meta.max_steps // chunk_steps + 2))
+                chunks = 0
+                pol_lane = _lane_policies(pol_np, sched)
             while sched.active:
-                carry = chunk(consts_s, pol_lane, carry)
+                with TraceAnnotation("repro.fleet.chunk"):
+                    stats.count_uploads(pol_lane)
+                    carry = chunk(consts_s, pol_lane, carry)
                 chunks += 1
                 stats.chunks += 1
                 if chunks > max_chunks:
                     raise RuntimeError(
                         f"fleet cohort {gkey} exceeded {max_chunks} chunks "
                         "without draining — engine not making progress")
-                done = np.asarray(carry[2])
-                retire, refill = sched.step(done)
-                if retire:
-                    host_s = [np.asarray(a) for a in carry[0]]
-                    if out is None:
-                        state_cls = type(carry[0])
-                        out = [np.empty((S, P) + a.shape[1:], a.dtype)
-                               for a in host_s]
-                    lanes = np.array([l for l, _ in retire])
-                    mems = np.array([m for _, m in retire])
-                    for o, h in zip(out, host_s):
-                        o[si, mems] = h[lanes]
-                    steps_leaf = host_s[carry[0]._fields.index("steps")]
-                    for lane, member in retire:
-                        steps = float(steps_leaf[lane])
-                        predictor.observe(
-                            (sname, sig, exp.policy_names[member]), steps)
-                        predictor.observe(gkey, steps)
+                with TraceAnnotation("repro.fleet.sync"):
+                    done = stats.fetch(carry[2])
+                with TraceAnnotation("repro.fleet.retire"):
+                    retire, refill = sched.step(done)
+                    if retire:
+                        host_s = [stats.fetch(a) for a in carry[0]]
+                        if out is None:
+                            state_cls = type(carry[0])
+                            out = [np.empty((S, P) + a.shape[1:], a.dtype)
+                                   for a in host_s]
+                        lanes = np.array([l for l, _ in retire])
+                        mems = np.array([m for _, m in retire])
+                        for o, h in zip(out, host_s):
+                            o[si, mems] = h[lanes]
+                        steps_leaf = host_s[carry[0]._fields.index("steps")]
+                        for lane, member in retire:
+                            steps = float(steps_leaf[lane])
+                            predictor.observe(
+                                (sname, sig, exp.policy_names[member]),
+                                steps)
+                            predictor.observe(gkey, steps)
                 if refill.any():
-                    stats.refills += int(refill.sum())
-                    mask = jnp.asarray(refill)
-                    # where refilled: back to the t=0 carry (done leaf
-                    # included — a sim finished at t=0 stays frozen and
-                    # retires with its s0 state, exactly like serial)
-                    carry = _refill_program(meta, W)(mask, consts_s, carry)
-                    pol_lane = _lane_policies(pol_np, sched)
+                    with TraceAnnotation("repro.fleet.refill"):
+                        stats.refills += int(refill.sum())
+                        mask = stats.upload(refill)
+                        # where refilled: back to the t=0 carry (done leaf
+                        # included — a sim finished at t=0 stays frozen
+                        # and retires with its s0 state, exactly like
+                        # serial)
+                        carry = _refill_program(meta, W)(mask, consts_s,
+                                                         carry)
+                        pol_lane = _lane_policies(pol_np, sched)
 
     states = state_cls(*out)   # the serial runner's [S, P, ...] grid
     if S == 1:   # Results keeps a scenario axis on consts

@@ -1723,290 +1723,306 @@ def _make_aux(c: EngineConsts, pol) -> Dict[str, jnp.ndarray]:
 
 
 def _step(c: EngineConsts, meta, pol, aux, carry):
+    """One event.  Every statement runs under one of six
+    ``jax.named_scope`` phases, which XLA keeps in each op's ``op_name``,
+    so a device trace splits the step's time by phase: ``admit_place``,
+    ``activate``, ``chaos`` (failures, degradation, speculation),
+    ``rates``, ``advance`` (dt-min, energy, clock) and ``complete``."""
     s, cache = carry
     if meta.has_failures:
-        s, cache = _apply_failures(c, meta, pol, s, cache)
-    s, placed, admit_now = _admit_and_place(c, meta, pol, aux, s)
-    if meta.has_ctrl:
-        # migrate BEFORE the cache refresh so re-homed endpoints resolve
-        # against the new placement this very step (DESIGN.md §10)
-        s, cache, migrated = _maybe_migrate(c, meta, pol, s, cache)
-        placed = placed | migrated
-    # placement changed -> the packet endpoint/pair cache is stale
-    cache = jax.lax.cond(
-        placed, lambda: {**cache, **_endpoint_cache(c, meta, s)},
-        lambda: cache)
-    # the fused network pass: route links, active mask, channel counts and
-    # effective bandwidth come out of activation ONCE per step and feed
-    # rates + energy below (DESIGN.md §8)
-    if meta.has_ctrl:
-        install_static = static_policy_value(pol["install_mode"])
-        if install_static is None or install_static == INSTALL_PROACTIVE:
-            s = jax.lax.cond(
-                (jnp.any(admit_now)
-                 & (pol["install_mode"] == INSTALL_PROACTIVE)
-                 & (pol["routing"] == ROUTE_SDN)),
-                lambda s: _preinstall(c, meta, pol, aux, cache, s,
-                                      admit_now),
-                lambda s: s, s)
-        s, links, p_active, nc, link_bw = _activate_ctrl(c, meta, pol, aux,
-                                                         cache, s)
-    else:
-        s, links, p_active, nc, link_bw = _activate(c, meta, pol, aux,
-                                                    cache, s)
+        with jax.named_scope("chaos"):
+            s, cache = _apply_failures(c, meta, pol, s, cache)
+    with jax.named_scope("admit_place"):
+        s, placed, admit_now = _admit_and_place(c, meta, pol, aux, s)
+        if meta.has_ctrl:
+            # migrate BEFORE the cache refresh so re-homed endpoints resolve
+            # against the new placement this very step (DESIGN.md §10)
+            s, cache, migrated = _maybe_migrate(c, meta, pol, s, cache)
+            placed = placed | migrated
+    with jax.named_scope("activate"):
+        # placement changed -> the packet endpoint/pair cache is stale
+        cache = jax.lax.cond(
+            placed, lambda: {**cache, **_endpoint_cache(c, meta, s)},
+            lambda: cache)
+        # the fused network pass: route links, active mask, channel counts and
+        # effective bandwidth come out of activation ONCE per step and feed
+        # rates + energy below (DESIGN.md §8)
+        if meta.has_ctrl:
+            install_static = static_policy_value(pol["install_mode"])
+            if install_static is None or install_static == INSTALL_PROACTIVE:
+                s = jax.lax.cond(
+                    (jnp.any(admit_now)
+                     & (pol["install_mode"] == INSTALL_PROACTIVE)
+                     & (pol["routing"] == ROUTE_SDN)),
+                    lambda s: _preinstall(c, meta, pol, aux, cache, s,
+                                          admit_now),
+                    lambda s: s, s)
+            s, links, p_active, nc, link_bw = _activate_ctrl(c, meta, pol, aux,
+                                                             cache, s)
+        else:
+            s, links, p_active, nc, link_bw = _activate(c, meta, pol, aux,
+                                                        cache, s)
     if meta.spec_slots > 0:
-        # clone housekeeping + straggler launch happen AFTER activation
-        # (so just-activated tasks are census-visible) and BEFORE rates
-        # (so a launched clone shares its VM from this very interval)
-        s = _speculate(c, meta, pol, aux, s)
-    pkt_rate, task_rate, t_active, spec_rate = _rates(c, meta, pol, s,
-                                                      links, p_active,
-                                                      nc, link_bw)
+        with jax.named_scope("chaos"):
+            # clone housekeeping + straggler launch happen AFTER activation
+            # (so just-activated tasks are census-visible) and BEFORE rates
+            # (so a launched clone shares its VM from this very interval)
+            s = _speculate(c, meta, pol, aux, s)
+    with jax.named_scope("rates"):
+        pkt_rate, task_rate, t_active, spec_rate = _rates(c, meta, pol, s,
+                                                          links, p_active,
+                                                          nc, link_bw)
 
-    # earliest horizon (Eq. 4 generalized)
-    dt_p = jnp.min(jnp.where(p_active & (pkt_rate > 0),
-                             s.pkt_rem / pkt_rate, _INF))
-    dt_t = jnp.min(jnp.where(t_active & (task_rate > 0),
-                             s.task_rem / task_rate, _INF))
-    future = (~s.job_admitted) & c.job_valid & (c.job_release > s.time)
-    dt_r = jnp.min(jnp.where(future, c.job_release - s.time, _INF))
-    dt = jnp.minimum(jnp.minimum(dt_p, dt_t), dt_r)
-    if meta.has_failures:
-        # fail/recover instants are rate breakpoints exactly like job
-        # releases — they join the analytic min, no event heap needed
-        # (DESIGN.md §7); ``fail_breaks`` is the four schedule tensors
-        # pre-concatenated so this is ONE masked min (DESIGN.md §8)
-        dt_f = jnp.min(jnp.where(c.fail_breaks > s.time,
-                                 c.fail_breaks - s.time, _INF))
-        dt = jnp.minimum(dt, dt_f)
-    if meta.has_degradation:
-        # gray-window edges are rate breakpoints exactly like outages
-        # (DESIGN.md §13); ``deg_breaks`` pre-concatenates the four
-        # schedule tensors so this is ONE masked min
-        dt_d = jnp.min(jnp.where(c.deg_breaks > s.time,
-                                 c.deg_breaks - s.time, _INF))
-        dt = jnp.minimum(dt, dt_d)
-    if meta.has_ctrl:
-        # rule-install completions and migration resumes are rate
-        # breakpoints exactly like failures (DESIGN.md §10): the analytic
-        # min lands the clock exactly on each wake instant
-        dt_c = jnp.min(jnp.where((s.pkt_state == INSTALLING)
-                                 & (s.pkt_ready_t > s.time),
-                                 s.pkt_ready_t - s.time, _INF))
-        dt_m = jnp.min(jnp.where(s.vm_mig_until > s.time,
-                                 s.vm_mig_until - s.time, _INF))
-        dt = jnp.minimum(dt, jnp.minimum(dt_c, dt_m))
-        # controller failover edges (primary down, election gap end,
-        # primary back — DESIGN.md §13) are breakpoints too; all three
-        # are inf when failover is unconfigured
-        fo = jnp.stack([
-            c.ctrl_fail_t,
-            jnp.minimum(c.ctrl_fail_t + c.ctrl_failover_delay,
-                        c.ctrl_recover_t),
-            c.ctrl_recover_t])
-        dt_fo = jnp.min(jnp.where(fo > s.time, fo - s.time, _INF))
-        dt = jnp.minimum(dt, dt_fo)
-    if meta.spec_slots > 0:
-        # clone finishes join the min like task finishes
-        dt_s = jnp.min(jnp.where((s.spec_of >= 0) & (spec_rate > 0),
-                                 s.spec_rem / spec_rate, _INF))
-        dt = jnp.minimum(dt, dt_s)
-    stalled = jnp.isinf(dt)
-    dt = jnp.where(stalled, 0.0, dt)
+    with jax.named_scope("advance"):
+        # earliest horizon (Eq. 4 generalized)
+        dt_p = jnp.min(jnp.where(p_active & (pkt_rate > 0),
+                                 s.pkt_rem / pkt_rate, _INF))
+        dt_t = jnp.min(jnp.where(t_active & (task_rate > 0),
+                                 s.task_rem / task_rate, _INF))
+        future = (~s.job_admitted) & c.job_valid & (c.job_release > s.time)
+        dt_r = jnp.min(jnp.where(future, c.job_release - s.time, _INF))
+        dt = jnp.minimum(jnp.minimum(dt_p, dt_t), dt_r)
+        if meta.has_failures:
+            # fail/recover instants are rate breakpoints exactly like job
+            # releases — they join the analytic min, no event heap needed
+            # (DESIGN.md §7); ``fail_breaks`` is the four schedule tensors
+            # pre-concatenated so this is ONE masked min (DESIGN.md §8)
+            dt_f = jnp.min(jnp.where(c.fail_breaks > s.time,
+                                     c.fail_breaks - s.time, _INF))
+            dt = jnp.minimum(dt, dt_f)
+        if meta.has_degradation:
+            # gray-window edges are rate breakpoints exactly like outages
+            # (DESIGN.md §13); ``deg_breaks`` pre-concatenates the four
+            # schedule tensors so this is ONE masked min
+            dt_d = jnp.min(jnp.where(c.deg_breaks > s.time,
+                                     c.deg_breaks - s.time, _INF))
+            dt = jnp.minimum(dt, dt_d)
+        if meta.has_ctrl:
+            # rule-install completions and migration resumes are rate
+            # breakpoints exactly like failures (DESIGN.md §10): the analytic
+            # min lands the clock exactly on each wake instant
+            dt_c = jnp.min(jnp.where((s.pkt_state == INSTALLING)
+                                     & (s.pkt_ready_t > s.time),
+                                     s.pkt_ready_t - s.time, _INF))
+            dt_m = jnp.min(jnp.where(s.vm_mig_until > s.time,
+                                     s.vm_mig_until - s.time, _INF))
+            dt = jnp.minimum(dt, jnp.minimum(dt_c, dt_m))
+            # controller failover edges (primary down, election gap end,
+            # primary back — DESIGN.md §13) are breakpoints too; all three
+            # are inf when failover is unconfigured
+            fo = jnp.stack([
+                c.ctrl_fail_t,
+                jnp.minimum(c.ctrl_fail_t + c.ctrl_failover_delay,
+                            c.ctrl_recover_t),
+                c.ctrl_recover_t])
+            dt_fo = jnp.min(jnp.where(fo > s.time, fo - s.time, _INF))
+            dt = jnp.minimum(dt, dt_fo)
+        if meta.spec_slots > 0:
+            # clone finishes join the min like task finishes
+            dt_s = jnp.min(jnp.where((s.spec_of >= 0) & (spec_rate > 0),
+                                     s.spec_rem / spec_rate, _INF))
+            dt = jnp.minimum(dt, dt_s)
+        stalled = jnp.isinf(dt)
+        dt = jnp.where(stalled, 0.0, dt)
 
-    # energy (power is constant over [t, t+dt))
-    vm_safe = jnp.maximum(s.task_vm, 0)
-    host_of_task = _vm_host(c, meta, s)[vm_safe]
-    # MIPS-by-host via a compacted per-active-task accumulation, not a
-    # task-axis scatter-add: the scatter runs EVERY step, and under a
-    # vmapped cohort an XLA/CPU scatter serializes one row per lane
-    # (DESIGN.md §9) — it alone cost the xl fleet ~10% batch efficiency.
-    # Ascending task order is the scatter's own update order and the
-    # skipped zero-adds are f32-exact (x + 0.0 == x away from -0.0/NaN,
-    # and rate partial sums are finite and non-negative), so host_energy
-    # stays bit-identical to the reference scatter.
-    n_t_e = host_of_task.shape[0]
-    hiota = jnp.arange(c.host_total_mips.shape[0], dtype=jnp.int32)
-    order_e = jnp.sort(jnp.where(t_active,
-                                 jnp.arange(n_t_e, dtype=jnp.int32), n_t_e))
+        # energy (power is constant over [t, t+dt))
+        vm_safe = jnp.maximum(s.task_vm, 0)
+        host_of_task = _vm_host(c, meta, s)[vm_safe]
+        # MIPS-by-host via a compacted per-active-task accumulation, not a
+        # task-axis scatter-add: the scatter runs EVERY step, and under a
+        # vmapped cohort an XLA/CPU scatter serializes one row per lane
+        # (DESIGN.md §9) — it alone cost the xl fleet ~10% batch efficiency.
+        # Ascending task order is the scatter's own update order and the
+        # skipped zero-adds are f32-exact (x + 0.0 == x away from -0.0/NaN,
+        # and rate partial sums are finite and non-negative), so host_energy
+        # stays bit-identical to the reference scatter.
+        n_t_e = host_of_task.shape[0]
+        hiota = jnp.arange(c.host_total_mips.shape[0], dtype=jnp.int32)
+        order_e = jnp.sort(jnp.where(t_active,
+                                     jnp.arange(n_t_e, dtype=jnp.int32), n_t_e))
 
-    def mips_one(k, m):
-        i = order_e[jnp.minimum(k, n_t_e - 1)]
-        return m + jnp.where(hiota == host_of_task[i], task_rate[i], 0.0)
+        def mips_one(k, m):
+            i = order_e[jnp.minimum(k, n_t_e - 1)]
+            return m + jnp.where(hiota == host_of_task[i], task_rate[i], 0.0)
 
-    mips_used = jax.lax.fori_loop(0, jnp.sum(t_active.astype(jnp.int32)),
-                                  mips_one, jnp.zeros_like(c.host_total_mips))
-    if meta.spec_slots > 0:
-        # clones burn host cycles like real tasks; the slot axis is tiny
-        # (n_jobs * spec_slots) so a dense one-hot contraction is cheaper
-        # than extending the compacted loop
-        clone_host = _vm_host(c, meta, s)[jnp.maximum(s.spec_vm, 0)]
-        mips_used = mips_used + jnp.sum(
-            jnp.where((s.spec_of >= 0)[:, None]
-                      & (hiota[None, :] == clone_host[:, None]),
-                      spec_rate[:, None], 0.0), axis=0)
-    # utilization is relative to the CURRENT (possibly degraded) capacity:
-    # a saturated gray host draws full power for less work (DESIGN.md §13);
-    # _effective_host_mips is exactly host_total_mips when degradation is
-    # off, keeping the off-switch trace unchanged
-    util = jnp.clip(mips_used / jnp.maximum(_effective_host_mips(c, meta, s),
-                                            1e-9), 0.0, 1.0)
-    if meta.has_failures:
-        util = jnp.where(s.host_dead, 0.0, util)  # dead hosts draw 0 W
-    host_energy = s.host_energy + host_power(util, meta.energy) * dt
-    host_busy = s.host_busy + jnp.where(util > 0, dt, 0.0)
-    live_link = (nc > 0).astype(jnp.int32)
-    if meta.has_failures:
-        live_link = jnp.where(s.link_dead, 0, live_link)  # port is down
-    # link-axis one-hot contraction, not two scatters (vmap serialization,
-    # DESIGN.md §9); only the switch slice of the node axis is needed
-    sw_iota = meta.n_hosts + jnp.arange(meta.n_switches, dtype=jnp.int32)
-    sw_ports = jnp.sum(
-        ((c.link_src[:, None] == sw_iota[None, :]).astype(jnp.int32)
-         + (c.link_dst[:, None] == sw_iota[None, :]).astype(jnp.int32))
-        * live_link[:, None], axis=0)
-    switch_energy = s.switch_energy + switch_power(sw_ports, meta.energy) * dt
+        mips_used = jax.lax.fori_loop(0, jnp.sum(t_active.astype(jnp.int32)),
+                                      mips_one, jnp.zeros_like(c.host_total_mips))
+        if meta.spec_slots > 0:
+            # clones burn host cycles like real tasks; the slot axis is tiny
+            # (n_jobs * spec_slots) so a dense one-hot contraction is cheaper
+            # than extending the compacted loop
+            clone_host = _vm_host(c, meta, s)[jnp.maximum(s.spec_vm, 0)]
+            mips_used = mips_used + jnp.sum(
+                jnp.where((s.spec_of >= 0)[:, None]
+                          & (hiota[None, :] == clone_host[:, None]),
+                          spec_rate[:, None], 0.0), axis=0)
+        # utilization is relative to the CURRENT (possibly degraded) capacity:
+        # a saturated gray host draws full power for less work (DESIGN.md §13);
+        # _effective_host_mips is exactly host_total_mips when degradation is
+        # off, keeping the off-switch trace unchanged
+        util = jnp.clip(mips_used / jnp.maximum(_effective_host_mips(c, meta, s),
+                                                1e-9), 0.0, 1.0)
+        if meta.has_failures:
+            util = jnp.where(s.host_dead, 0.0, util)  # dead hosts draw 0 W
+        host_energy = s.host_energy + host_power(util, meta.energy) * dt
+        host_busy = s.host_busy + jnp.where(util > 0, dt, 0.0)
+        live_link = (nc > 0).astype(jnp.int32)
+        if meta.has_failures:
+            live_link = jnp.where(s.link_dead, 0, live_link)  # port is down
+        # link-axis one-hot contraction, not two scatters (vmap serialization,
+        # DESIGN.md §9); only the switch slice of the node axis is needed
+        sw_iota = meta.n_hosts + jnp.arange(meta.n_switches, dtype=jnp.int32)
+        sw_ports = jnp.sum(
+            ((c.link_src[:, None] == sw_iota[None, :]).astype(jnp.int32)
+             + (c.link_dst[:, None] == sw_iota[None, :]).astype(jnp.int32))
+            * live_link[:, None], axis=0)
+        switch_energy = s.switch_energy + switch_power(sw_ports, meta.energy) * dt
 
-    if meta.has_failures:
-        # per-job downtime: admitted, not done, and NOTHING of the job's
-        # moves over [t, t+dt) — the failure-induced outage metric
-        n_j = s.job_downtime.shape[0]
-        prog_t = t_active & (task_rate > 0) & c.task_valid
-        prog_p = p_active & (pkt_rate > 0) & c.pkt_valid
-        # grouped ANY via one-hot masks, not two scatter-maxes (vmap
-        # serialization, DESIGN.md §9); max over {0,1} == any
-        jiota = jnp.arange(n_j, dtype=jnp.int32)
-        job_prog = (
-            jnp.any((jnp.maximum(c.task_job, 0)[:, None] == jiota[None, :])
-                    & prog_t[:, None], axis=0)
-            | jnp.any((jnp.maximum(c.pkt_job, 0)[:, None] == jiota[None, :])
-                      & prog_p[:, None], axis=0)).astype(jnp.int32)
-        job_live = (s.job_admitted & (s.job_out_done < c.job_n_out)
-                    & c.job_valid)
-        job_downtime = s.job_downtime + jnp.where(
-            job_live & (job_prog == 0), dt, 0.0)
-    else:
-        job_downtime = s.job_downtime
+    with jax.named_scope("chaos"):
+        if meta.has_failures:
+            # per-job downtime: admitted, not done, and NOTHING of the job's
+            # moves over [t, t+dt) — the failure-induced outage metric
+            n_j = s.job_downtime.shape[0]
+            prog_t = t_active & (task_rate > 0) & c.task_valid
+            prog_p = p_active & (pkt_rate > 0) & c.pkt_valid
+            # grouped ANY via one-hot masks, not two scatter-maxes (vmap
+            # serialization, DESIGN.md §9); max over {0,1} == any
+            jiota = jnp.arange(n_j, dtype=jnp.int32)
+            job_prog = (
+                jnp.any((jnp.maximum(c.task_job, 0)[:, None] == jiota[None, :])
+                        & prog_t[:, None], axis=0)
+                | jnp.any((jnp.maximum(c.pkt_job, 0)[:, None] == jiota[None, :])
+                          & prog_p[:, None], axis=0)).astype(jnp.int32)
+            job_live = (s.job_admitted & (s.job_out_done < c.job_n_out)
+                        & c.job_valid)
+            job_downtime = s.job_downtime + jnp.where(
+                job_live & (job_prog == 0), dt, 0.0)
+        else:
+            job_downtime = s.job_downtime
 
-    if meta.has_degradation:
-        # wall-clock seconds with ANY live gray window open — the
-        # degraded-exposure metric (same pass-through shape as
-        # job_downtime: off-replicas in a packed sweep accumulate 0)
-        any_deg = (jnp.any((c.host_slow_t <= s.time)
-                           & (s.time < c.host_restore_t)
-                           & (c.host_deg_factor != 1.0))
-                   | jnp.any((c.link_slow_t <= s.time)
-                             & (s.time < c.link_restore_t)
-                             & (c.link_deg_factor != 1.0)))
-        degraded_time = s.degraded_time + jnp.where(any_deg, dt, 0.0)
-    else:
-        degraded_time = s.degraded_time
+        if meta.has_degradation:
+            # wall-clock seconds with ANY live gray window open — the
+            # degraded-exposure metric (same pass-through shape as
+            # job_downtime: off-replicas in a packed sweep accumulate 0)
+            any_deg = (jnp.any((c.host_slow_t <= s.time)
+                               & (s.time < c.host_restore_t)
+                               & (c.host_deg_factor != 1.0))
+                       | jnp.any((c.link_slow_t <= s.time)
+                                 & (s.time < c.link_restore_t)
+                                 & (c.link_deg_factor != 1.0)))
+            degraded_time = s.degraded_time + jnp.where(any_deg, dt, 0.0)
+        else:
+            degraded_time = s.degraded_time
 
-    # advance
-    time = s.time + dt
-    pkt_rem = jnp.where(p_active, s.pkt_rem - pkt_rate * dt, s.pkt_rem)
-    task_rem = jnp.where(t_active, s.task_rem - task_rate * dt, s.task_rem)
-    p_done_now = p_active & (pkt_rem <= aux["pkt_tol"])
-    t_done_now = t_active & (task_rem <= aux["task_tol"])
+    with jax.named_scope("advance"):
+        time = s.time + dt
+        pkt_rem = jnp.where(p_active, s.pkt_rem - pkt_rate * dt, s.pkt_rem)
+        task_rem = jnp.where(t_active, s.task_rem - task_rate * dt, s.task_rem)
+        steps = s.steps + 1
 
-    pkt_state = jnp.where(p_done_now, DONE, s.pkt_state)
-    pkt_finish = jnp.where(p_done_now, time, s.pkt_finish)
-    task_state = jnp.where(t_done_now, DONE, s.task_state)
-    task_finish = jnp.where(t_done_now, time, s.task_finish)
+        if meta.has_ctrl:
+            # count the primary→backup handover once, when the clock passes
+            # ctrl_fail_t (a dt breakpoint, so the crossing is exact)
+            crossed = (s.time <= c.ctrl_fail_t) & (time > c.ctrl_fail_t)
+            ctrl_failovers = s.ctrl_failovers + crossed.astype(jnp.int32)
+        else:
+            ctrl_failovers = s.ctrl_failovers
 
-    if meta.has_ctrl:
-        # count the primary→backup handover once, when the clock passes
-        # ctrl_fail_t (a dt breakpoint, so the crossing is exact)
-        crossed = (s.time <= c.ctrl_fail_t) & (time > c.ctrl_fail_t)
-        ctrl_failovers = s.ctrl_failovers + crossed.astype(jnp.int32)
-    else:
-        ctrl_failovers = s.ctrl_failovers
+    with jax.named_scope("complete"):
+        p_done_now = p_active & (pkt_rem <= aux["pkt_tol"])
+        t_done_now = t_active & (task_rem <= aux["task_tol"])
 
-    # completions feed gates + release their channels.  Only a handful of
-    # packets finish per event, so this is a compacted scan over the done
-    # set — pop order is a cursor-chained masked min per trip (ascending
-    # packet index, same order the old argmax-chain popped; a precomputed
-    # packet-axis sort runs EVERY step, done set or not, and was one of
-    # the largest single per-step costs) instead of three packet-axis
-    # scatters (DESIGN.md §8).  The per-trip updates are one-hot
-    # compare-sums, NOT scatters: under vmap an XLA/CPU scatter serializes
-    # one row per lane, and at fleet widths the three scatters per trip
-    # dominated the whole step.  All updates are commutative integer adds,
-    # so the carried ``nc`` stays exact (mirroring activation's bumps) —
-    # bit-identical.
-    n_t_pad = s.task_got.shape[0]
-    n_j_pad = s.job_out_done.shape[0]
-    n_p_pad = p_done_now.shape[0]
-    n_done = jnp.sum(p_done_now.astype(jnp.int32))
-    idx_p = jnp.arange(n_p_pad, dtype=jnp.int32)
-    liota = jnp.arange(nc.shape[0], dtype=jnp.int32)
-    tiota = jnp.arange(n_t_pad, dtype=jnp.int32)
-    jiota = jnp.arange(n_j_pad, dtype=jnp.int32)
+        pkt_state = jnp.where(p_done_now, DONE, s.pkt_state)
+        pkt_finish = jnp.where(p_done_now, time, s.pkt_finish)
+        task_state = jnp.where(t_done_now, DONE, s.task_state)
+        task_finish = jnp.where(t_done_now, time, s.task_finish)
 
-    def complete_one(k, carry):
-        nc_c, task_got, job_out_done, cursor = carry
-        i = jnp.min(jnp.where(p_done_now & (idx_p > cursor), idx_p,
-                              n_p_pad))                 # k < n_done -> real
-        safe = jnp.minimum(i, n_p_pad - 1)
-        links_i = c.routes[jnp.maximum(s.pkt_pair[safe], 0),
-                           jnp.maximum(s.pkt_cand[safe], 0)]
-        nc_c = nc_c - jnp.sum((links_i[:, None] == liota[None, :])
-                              .astype(jnp.int32), axis=0)
-        feeds_i = c.pkt_feeds_task[safe]
-        task_got = task_got + (tiota == feeds_i).astype(jnp.int32)
-        jtgt = jnp.where(feeds_i < 0, jnp.maximum(c.pkt_job[safe], 0), -1)
-        job_out_done = job_out_done + (jiota == jtgt).astype(jnp.int32)
-        return nc_c, task_got, job_out_done, i
+        # completions feed gates + release their channels.  Only a handful of
+        # packets finish per event, so this is a compacted scan over the done
+        # set — pop order is a cursor-chained masked min per trip (ascending
+        # packet index, same order the old argmax-chain popped; a precomputed
+        # packet-axis sort runs EVERY step, done set or not, and was one of
+        # the largest single per-step costs) instead of three packet-axis
+        # scatters (DESIGN.md §8).  The per-trip updates are one-hot
+        # compare-sums, NOT scatters: under vmap an XLA/CPU scatter serializes
+        # one row per lane, and at fleet widths the three scatters per trip
+        # dominated the whole step.  All updates are commutative integer adds,
+        # so the carried ``nc`` stays exact (mirroring activation's bumps) —
+        # bit-identical.
+        n_t_pad = s.task_got.shape[0]
+        n_j_pad = s.job_out_done.shape[0]
+        n_p_pad = p_done_now.shape[0]
+        n_done = jnp.sum(p_done_now.astype(jnp.int32))
+        idx_p = jnp.arange(n_p_pad, dtype=jnp.int32)
+        liota = jnp.arange(nc.shape[0], dtype=jnp.int32)
+        tiota = jnp.arange(n_t_pad, dtype=jnp.int32)
+        jiota = jnp.arange(n_j_pad, dtype=jnp.int32)
 
-    nc_next, task_got, job_out_done, _ = jax.lax.fori_loop(
-        0, n_done, complete_one,
-        (nc, s.task_got, s.job_out_done, jnp.int32(-1)))
-    newly_job_done = (job_out_done >= c.job_n_out) & \
-        (s.job_out_done < c.job_n_out) & c.job_valid
-    job_done_t = jnp.where(newly_job_done, time, s.job_done_t)
-    # task-axis one-hot contraction, not a scatter (same vmap reason);
-    # integer adds commute -> bit-identical
-    vm_iota = jnp.arange(s.vm_load.shape[0], dtype=jnp.int32)
-    vm_load = s.vm_load - jnp.sum(
-        (vm_safe[:, None] == vm_iota[None, :])
-        & t_done_now[:, None], axis=0).astype(jnp.int32)
+        def complete_one(k, carry):
+            nc_c, task_got, job_out_done, cursor = carry
+            i = jnp.min(jnp.where(p_done_now & (idx_p > cursor), idx_p,
+                                  n_p_pad))                 # k < n_done -> real
+            safe = jnp.minimum(i, n_p_pad - 1)
+            links_i = c.routes[jnp.maximum(s.pkt_pair[safe], 0),
+                               jnp.maximum(s.pkt_cand[safe], 0)]
+            nc_c = nc_c - jnp.sum((links_i[:, None] == liota[None, :])
+                                  .astype(jnp.int32), axis=0)
+            feeds_i = c.pkt_feeds_task[safe]
+            task_got = task_got + (tiota == feeds_i).astype(jnp.int32)
+            jtgt = jnp.where(feeds_i < 0, jnp.maximum(c.pkt_job[safe], 0), -1)
+            job_out_done = job_out_done + (jiota == jtgt).astype(jnp.int32)
+            return nc_c, task_got, job_out_done, i
 
-    spec_of, spec_rem = s.spec_of, s.spec_rem
-    spec_wins, spec_wasted = s.spec_wins, s.spec_wasted
-    if meta.spec_slots > 0:
-        # clone completions: first finish WINS the race (DESIGN.md §13).
-        # A tie on the same breakpoint goes to the original, so the
-        # speculation axis can only ever help a task's finish time.
-        s_orig = jnp.maximum(spec_of, 0)
-        s_live = spec_of >= 0
-        spec_rem = jnp.where(s_live, spec_rem - spec_rate * dt, spec_rem)
-        clone_done = s_live & (spec_rem <= aux["task_tol"][s_orig])
-        win = clone_done & ~t_done_now[s_orig]
-        # task-axis effect of the wins (one-hot, not a scatter)
-        win_t = jnp.sum((s_orig[:, None] == tiota[None, :])
-                        & win[:, None], axis=0) > 0
-        task_state = jnp.where(win_t, DONE, task_state)
-        task_finish = jnp.where(win_t, time, task_finish)
-        task_rem = jnp.where(win_t, 0.0, task_rem)
-        # the losing copy frees its container: the overtaken ORIGINAL's VM
-        # on a win, the clone's VM on every clone finish
-        vm_load = vm_load - jnp.sum(
+        nc_next, task_got, job_out_done, _ = jax.lax.fori_loop(
+            0, n_done, complete_one,
+            (nc, s.task_got, s.job_out_done, jnp.int32(-1)))
+        newly_job_done = (job_out_done >= c.job_n_out) & \
+            (s.job_out_done < c.job_n_out) & c.job_valid
+        job_done_t = jnp.where(newly_job_done, time, s.job_done_t)
+        # task-axis one-hot contraction, not a scatter (same vmap reason);
+        # integer adds commute -> bit-identical
+        vm_iota = jnp.arange(s.vm_load.shape[0], dtype=jnp.int32)
+        vm_load = s.vm_load - jnp.sum(
             (vm_safe[:, None] == vm_iota[None, :])
-            & win_t[:, None], axis=0).astype(jnp.int32)
-        vm_load = vm_load - jnp.sum(
-            (jnp.maximum(s.spec_vm, 0)[:, None] == vm_iota[None, :])
-            & clone_done[:, None], axis=0).astype(jnp.int32)
-        # wasted seconds: the original's whole run on a win, the clone's
-        # on a photo-finish loss (cancelled clones accrue in _speculate)
-        waste = jnp.where(win, time - s.task_start[s_orig],
-                          time - s.spec_start)
-        spec_wasted = spec_wasted + jnp.sum(
-            jnp.where(clone_done, waste, 0.0))
-        spec_wins = spec_wins + jnp.sum(win.astype(jnp.int32))
-        spec_of = jnp.where(clone_done, -1, spec_of)
+            & t_done_now[:, None], axis=0).astype(jnp.int32)
+
+    with jax.named_scope("chaos"):
+        spec_of, spec_rem = s.spec_of, s.spec_rem
+        spec_wins, spec_wasted = s.spec_wins, s.spec_wasted
+        if meta.spec_slots > 0:
+            # clone completions: first finish WINS the race (DESIGN.md §13).
+            # A tie on the same breakpoint goes to the original, so the
+            # speculation axis can only ever help a task's finish time.
+            s_orig = jnp.maximum(spec_of, 0)
+            s_live = spec_of >= 0
+            spec_rem = jnp.where(s_live, spec_rem - spec_rate * dt, spec_rem)
+            clone_done = s_live & (spec_rem <= aux["task_tol"][s_orig])
+            win = clone_done & ~t_done_now[s_orig]
+            # task-axis effect of the wins (one-hot, not a scatter)
+            win_t = jnp.sum((s_orig[:, None] == tiota[None, :])
+                            & win[:, None], axis=0) > 0
+            task_state = jnp.where(win_t, DONE, task_state)
+            task_finish = jnp.where(win_t, time, task_finish)
+            task_rem = jnp.where(win_t, 0.0, task_rem)
+            # the losing copy frees its container: the overtaken ORIGINAL's VM
+            # on a win, the clone's VM on every clone finish
+            vm_load = vm_load - jnp.sum(
+                (vm_safe[:, None] == vm_iota[None, :])
+                & win_t[:, None], axis=0).astype(jnp.int32)
+            vm_load = vm_load - jnp.sum(
+                (jnp.maximum(s.spec_vm, 0)[:, None] == vm_iota[None, :])
+                & clone_done[:, None], axis=0).astype(jnp.int32)
+            # wasted seconds: the original's whole run on a win, the clone's
+            # on a photo-finish loss (cancelled clones accrue in _speculate)
+            waste = jnp.where(win, time - s.task_start[s_orig],
+                              time - s.spec_start)
+            spec_wasted = spec_wasted + jnp.sum(
+                jnp.where(clone_done, waste, 0.0))
+            spec_wins = spec_wins + jnp.sum(win.astype(jnp.int32))
+            spec_of = jnp.where(clone_done, -1, spec_of)
 
     return s._replace(
-        time=time, steps=s.steps + 1, stalled=stalled,
+        time=time, steps=steps, stalled=stalled,
         job_out_done=job_out_done, job_done_t=job_done_t,
         task_state=task_state, task_rem=task_rem, task_got=task_got,
         task_finish=task_finish,

@@ -82,45 +82,46 @@ def build_route_table(topo: Topology, k_max: int = 8,
     so the shortest-path DAG is read straight off the distance matrix and
     enumerated by DFS.  Host-side, runs once at setup.
     """
-    n = topo.n_nodes
-    dist = hop_distances_np(topo.hop_matrix())
-    # adjacency list of directed links
-    out_links: list[list[tuple[int, int]]] = [[] for _ in range(n)]
-    for idx, (s, d) in enumerate(zip(topo.link_src, topo.link_dst)):
-        out_links[int(s)].append((int(d), idx))
+    with jax.profiler.TraceAnnotation("repro.front.routes"):
+        n = topo.n_nodes
+        dist = hop_distances_np(topo.hop_matrix())
+        # adjacency list of directed links
+        out_links: list[list[tuple[int, int]]] = [[] for _ in range(n)]
+        for idx, (s, d) in enumerate(zip(topo.link_src, topo.link_dst)):
+            out_links[int(s)].append((int(d), idx))
 
-    finite = dist[np.isfinite(dist)]
-    diam = int(finite.max()) if finite.size else 0
-    mh = max_hops if max_hops is not None else max(1, diam)
+        finite = dist[np.isfinite(dist)]
+        diam = int(finite.max()) if finite.size else 0
+        mh = max_hops if max_hops is not None else max(1, diam)
 
-    routes = np.full((n * n, k_max, mh), -1, dtype=np.int32)
-    n_cand = np.zeros((n * n,), dtype=np.int32)
-    route_len = np.zeros((n * n, k_max), dtype=np.int32)
-    truncated = False
+        routes = np.full((n * n, k_max, mh), -1, dtype=np.int32)
+        n_cand = np.zeros((n * n,), dtype=np.int32)
+        route_len = np.zeros((n * n, k_max), dtype=np.int32)
+        truncated = False
 
-    for src in range(n):
-        for dst in range(n):
-            if src == dst or not np.isfinite(dist[src, dst]):
-                continue
-            target = dist[src, dst]
-            found: list[list[int]] = []
-            stack: list[tuple[int, list[int]]] = [(src, [])]
-            while stack and len(found) < k_max + 1:
-                node, path = stack.pop()
-                if node == dst:
-                    found.append(path)
+        for src in range(n):
+            for dst in range(n):
+                if src == dst or not np.isfinite(dist[src, dst]):
                     continue
-                for (nxt, lidx) in out_links[node]:
-                    if dist[src, node] + 1 + dist[nxt, dst] == target:
-                        stack.append((nxt, path + [lidx]))
-            if len(found) > k_max:
-                truncated = True
-                found = found[:k_max]
-            p = src * n + dst
-            n_cand[p] = len(found)
-            for k, f in enumerate(found):
-                route_len[p, k] = len(f)
-                routes[p, k, : len(f)] = f
+                target = dist[src, dst]
+                found: list[list[int]] = []
+                stack: list[tuple[int, list[int]]] = [(src, [])]
+                while stack and len(found) < k_max + 1:
+                    node, path = stack.pop()
+                    if node == dst:
+                        found.append(path)
+                        continue
+                    for (nxt, lidx) in out_links[node]:
+                        if dist[src, node] + 1 + dist[nxt, dst] == target:
+                            stack.append((nxt, path + [lidx]))
+                if len(found) > k_max:
+                    truncated = True
+                    found = found[:k_max]
+                p = src * n + dst
+                n_cand[p] = len(found)
+                for k, f in enumerate(found):
+                    route_len[p, k] = len(f)
+                    routes[p, k, : len(f)] = f
     return RouteTable(routes=routes, n_cand=n_cand, route_len=route_len,
                       max_hops=mh, k_max=k_max, n_nodes=n, truncated=truncated)
 

@@ -15,6 +15,7 @@ import dataclasses
 from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core.ctrlplane import CtrlPlaneConfig
 from ..core.energy import EnergyParams
@@ -72,15 +73,16 @@ class Scenario:
     spec_slots: int = 0
 
     def build(self) -> SimSetup:
-        topo = self.topology()
-        return build_setup(list(self.workload()), make_cluster(
-            topo, vms_per_host=self.vms_per_host),
-            k_max=self.k_max, split=self.split,
-            failures=self.failures(topo) if self.failures else None,
-            ctrl=self.ctrl,
-            degradation=(self.degradation(topo)
-                         if self.degradation else None),
-            spec_slots=self.spec_slots)
+        with TraceAnnotation("repro.front.setup"):
+            topo = self.topology()
+            return build_setup(list(self.workload()), make_cluster(
+                topo, vms_per_host=self.vms_per_host),
+                k_max=self.k_max, split=self.split,
+                failures=self.failures(topo) if self.failures else None,
+                ctrl=self.ctrl,
+                degradation=(self.degradation(topo)
+                             if self.degradation else None),
+                spec_slots=self.spec_slots)
 
 
 _REGISTRY: Dict[str, Callable[..., Scenario]] = {}
