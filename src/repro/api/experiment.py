@@ -27,7 +27,7 @@ from jax.profiler import TraceAnnotation
 
 from ..core import policies as policy_mod
 from ..core.ctrlplane import CtrlPlaneConfig
-from ..core.engine import make_consts
+from ..core.engine import make_consts, static_policy_value
 from ..core.failures import DegradationSchedule, FailureSchedule
 from ..core.mapreduce import SimSetup
 from ..core.policies import as_policy_arrays, policy_fields
@@ -227,6 +227,7 @@ class Experiment:
         # once: repeated .run() calls are pack-free as well as trace-free
         self._built = None
         self._pol_arrays = None
+        self._static_pol = None
 
     # -- derived views ------------------------------------------------------
 
@@ -271,6 +272,19 @@ class Experiment:
                                     for k in stacked[0]}
         return self._pol_arrays
 
+    def _static_policies(self):
+        """The ``runners.STATIC_FIELDS`` every policy sets to one host-known
+        int, as ``{field: int}`` (memoized).  Read from the configs, never
+        from the uploaded arrays: the batched runners close over these
+        (DESIGN.md §6) without a device-to-host read."""
+        if self._static_pol is None:
+            self._static_pol = {}
+            for f in runners.STATIC_FIELDS:
+                vals = {_host_field(p, f) for _, p in self.policies}
+                if len(vals) == 1 and None not in vals:
+                    self._static_pol[f] = vals.pop()
+        return self._static_pol
+
     # -- execution ----------------------------------------------------------
 
     def run(self) -> Results:
@@ -283,13 +297,11 @@ class Experiment:
                 pols = jax.tree_util.tree_map(lambda a: a[0], pols)
                 states = runners.get_runner(meta, "single")(consts, pols)
                 expand = lambda a: a[None, None]              # noqa: E731
-            elif S == 1:
-                states = runners.get_runner(meta, "policy_batch")(consts,
-                                                                  pols)
-                expand = lambda a: a[None]                    # noqa: E731
             else:
-                states = runners.get_runner(meta, "grid")(consts, pols)
-                expand = None
+                kind = "policy_batch" if S == 1 else "grid"
+                states = runners.get_runner(meta, kind)(
+                    consts, {**pols, **self._static_policies()})
+                expand = (lambda a: a[None]) if S == 1 else None
         if expand is not None:
             states = jax.tree_util.tree_map(expand, states)
         if S == 1:   # Results keeps a scenario axis on consts
@@ -414,6 +426,22 @@ def _cross_ctrl(scenarios: List[Tuple[str, SimSetup]],
             name = f"{sname}/{cname}" if len(named) > 1 else sname
             out.append((name, dataclasses.replace(setup, ctrl=cfg)))
     return out
+
+
+def _host_field(pol, name: str) -> Optional[int]:
+    """``pol``'s value of policy field ``name`` as a Python int when the
+    config holds it on the host, else ``None`` (a device array, or a policy
+    object other than ``PolicyConfig`` / a mapping / ``None``)."""
+    default = policy_mod.policy_defaults()[name]
+    if pol is None:
+        v = default
+    elif isinstance(pol, Mapping):
+        v = pol.get(name, default)
+    elif isinstance(pol, policy_mod.PolicyConfig):
+        v = getattr(pol, name, default)
+    else:
+        return None
+    return static_policy_value(v)
 
 
 def _with_seed(pol, seed: int):

@@ -47,9 +47,10 @@ from ..core.simmeta import SimMeta
 from . import runners
 from .results import Results
 
-# the branch-selecting policy axes: uniform per cohort, closed over as
-# Python ints so the engine's dispatch specializes at trace time
-STATIC_FIELDS = ("routing", "traffic", "placement")
+# the branch-selecting policy axes (one definition, shared with the
+# batched runners): uniform per cohort, closed over as Python ints so the
+# engine's dispatch specializes at trace time
+STATIC_FIELDS = runners.STATIC_FIELDS
 
 
 class StepPredictor:

@@ -270,6 +270,11 @@ def _compiled_loops():
     fn, init = runners._make_fn(meta, "policy_batch", counted=False)
     s0 = jax.eval_shape(init, consts, pols)
     batch = jax.jit(fn).lower(consts, pols, s0).compile().as_text()
+    # the program Experiment.run gets: traffic and placement closed over
+    sig = runners.static_signature({**pols, **exp._static_policies()})
+    fn, _ = runners._make_fn(meta, "policy_batch", counted=False, sig=sig)
+    uniform = jax.jit(fn).lower(consts, runners._varying(pols, sig),
+                                s0).compile().as_text()
     chunk = engine.make_fleet_chunk(meta, {"routing": 1, "traffic": 0,
                                            "placement": 0}, 8)
     lane = {k: np.asarray(v) for k, v in pols.items()
@@ -278,6 +283,7 @@ def _compiled_loops():
                            consts)
     fleet_hlo = jax.jit(chunk).lower(consts, lane, carry).compile().as_text()
     return {"policy_batch": (batch, None),
+            "policy_batch_uniform": (uniform, None),
             "fleet_chunk": (fleet_hlo,
                             "cond/branch_1_fun/jit(_where)/select_n")}
 
@@ -287,7 +293,8 @@ def compiled_loops():
     return _compiled_loops()
 
 
-@pytest.mark.parametrize("program", ["policy_batch", "fleet_chunk"])
+@pytest.mark.parametrize("program", ["policy_batch", "policy_batch_uniform",
+                                     "fleet_chunk"])
 def test_every_loop_fusion_names_a_phase(compiled_loops, program):
     hlo, freeze = compiled_loops[program]
     fusions = _loop_fusions(hlo)
@@ -296,3 +303,19 @@ def test_every_loop_fusion_names_a_phase(compiled_loops, program):
     stray = [(c, f, on) for c, f, on in fusions
              if not op_phase(on) and not _loop_control(on, freeze)]
     assert not stray, stray
+
+
+def test_uniform_eq3_batch_has_no_waterfill_loop(compiled_loops):
+    """With traffic closed over as Eq. 3, the water-fill fill loop (a
+    ``while`` of scatter-adds under ``rates``) is gone from the batch
+    program; the generic program runs it under the batched select."""
+    def rates_ops(hlo, op):
+        return [line for line in hlo.splitlines()
+                if f" {op}(" in line and "/rates/" in line]
+
+    generic, _ = compiled_loops["policy_batch"]
+    uniform, _ = compiled_loops["policy_batch_uniform"]
+    assert len(rates_ops(generic, "while")) == 1
+    assert rates_ops(uniform, "while") == []
+    assert "/rates/" not in "".join(
+        line for line in uniform.splitlines() if "scatter" in line)
