@@ -22,8 +22,9 @@ for name in names:
     sc = get_scenario(name)
     setup = sc.build()
     topo = setup.cluster.topo
-    nc = setup.route_table.n_cand.reshape(topo.n_nodes, topo.n_nodes)
-    host_pairs = nc[: topo.n_hosts, : topo.n_hosts]
+    hosts = np.arange(topo.n_hosts)
+    host_pairs = setup.route_table.n_cand_between(hosts[:, None],
+                                                  hosts[None, :])
     off_diag = host_pairs[~np.eye(topo.n_hosts, dtype=bool)]
     print(f"{sc.name:22} {topo.n_hosts:3d} hosts {topo.n_switches:3d} switches "
           f"{topo.n_links:4d} links   host-pair route diversity: "

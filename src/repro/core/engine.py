@@ -49,7 +49,8 @@ from .policies import (INSTALL_PROACTIVE, JOBSEL_PRIORITY, JOBSEL_SJF,
                        MIG_CONGESTION, PLACE_RANDOM, PLACE_ROUND_ROBIN,
                        RECOVERY_RESTART, SPEC_ON, as_policy_arrays)
 from .routing import (ROUTE_SDN, flow_hash_u32, legacy_route_choice,
-                      sdn_route_choice)
+                      node_pair_hops, route_candidates, route_ends,
+                      route_links, sdn_route_choice)
 from .simmeta import SimMeta
 
 _INF = jnp.float32(jnp.inf)
@@ -105,9 +106,12 @@ def job_n_tasks_np(task_job, task_valid, n_jobs: int) -> np.ndarray:
 class EngineConsts(NamedTuple):
     """Static (replica-shared) tensors, baked from SimSetup."""
 
-    # routing
-    routes: jnp.ndarray      # [n_nodes^2, K, H]
-    n_cand: jnp.ndarray      # [n_nodes^2]
+    # routing (``routing.RouteTable``): candidates per pair of attachment
+    # nodes; a node pair's route is uplink + attachment route + downlink
+    routes: jnp.ndarray      # i32 [n_att, n_att, K, H]
+    n_cand: jnp.ndarray      # i32 [n_att, n_att]
+    # per node (attachment index, link to it, link from it); -1 = none
+    node_route: jnp.ndarray  # i32 [n_nodes, 3]
     link_bw: jnp.ndarray     # [n_links]
     link_src: jnp.ndarray
     link_dst: jnp.ndarray
@@ -186,10 +190,10 @@ class EngineConsts(NamedTuple):
     mig_cost: jnp.ndarray       # f32 []: compute pause per migration (s)
     mig_cooldown: jnp.ndarray   # f32 []: min quiet time between migrations
     mig_limit: jnp.ndarray      # i32 []: total migration budget per run
-    # candidate-0 hop count per (src*n_nodes+dst) pair — the migration
-    # policy's distance estimate; 0 on the diagonal, UNREACHABLE_HOPS where
-    # no route exists
-    pair_hops: jnp.ndarray      # i32 [n_nodes^2]
+    # candidate hop count per attachment pair — the migration policy's
+    # distance estimate (``routing.node_pair_hops``); 0 on the diagonal,
+    # UNREACHABLE_HOPS where no route exists
+    pair_hops: jnp.ndarray      # i32 [n_att, n_att]
     # controller failover (DESIGN.md §13): the primary controller is down
     # on [ctrl_fail_t, ctrl_recover_t); rule requests inside the first
     # ctrl_failover_delay seconds of the outage park until the backup's
@@ -224,6 +228,9 @@ class SimState(NamedTuple):
     pkt_rem: jnp.ndarray
     pkt_pair: jnp.ndarray
     pkt_cand: jnp.ndarray
+    # [n_packets, H] links of the chosen route, composed once when the
+    # route is chosen; meaningful while pkt_cand >= 0
+    pkt_links: jnp.ndarray
     pkt_start: jnp.ndarray
     pkt_finish: jnp.ndarray
     # vms / energy
@@ -315,22 +322,6 @@ def default_max_steps(setup: SimSetup) -> int:
     return steps
 
 
-UNREACHABLE_HOPS = 1 << 20  # pair_hops sentinel: no candidate route
-
-
-def pair_hops_np(route_len, n_cand, n_nodes: int) -> np.ndarray:
-    """Host-side candidate-0 hop count per node pair (the migration cost
-    estimate, DESIGN.md §10): 0 on the diagonal (intra-host is free),
-    ``UNREACHABLE_HOPS`` where no route exists.  Static per route table —
-    shared by make_consts and the packed-sweep builder."""
-    hops = np.where(np.asarray(n_cand) > 0,
-                    np.asarray(route_len)[:, 0], UNREACHABLE_HOPS)
-    hops = hops.astype(np.int32).copy()
-    diag = np.arange(n_nodes, dtype=np.int64)
-    hops[diag * n_nodes + diag] = 0
-    return hops
-
-
 def make_consts(setup: SimSetup) -> tuple[EngineConsts, SimMeta]:
     rt, cl = setup.route_table, setup.cluster
     sched = setup.failures
@@ -345,8 +336,7 @@ def make_consts(setup: SimSetup) -> tuple[EngineConsts, SimMeta]:
         deg.validate(cl.topo.n_hosts, cl.topo.n_links)
     cfg = (setup.ctrl or no_ctrl()).validate()
     consts = EngineConsts(
-        routes=jnp.asarray(rt.routes),
-        n_cand=jnp.asarray(rt.n_cand),
+        **{k: jnp.asarray(v) for k, v in rt.device_arrays().items()},
         link_bw=jnp.asarray(cl.topo.link_bw),
         link_src=jnp.asarray(cl.topo.link_src),
         link_dst=jnp.asarray(cl.topo.link_dst),
@@ -398,8 +388,6 @@ def make_consts(setup: SimSetup) -> tuple[EngineConsts, SimMeta]:
         mig_cost=jnp.asarray(cfg.mig_cost, jnp.float32),
         mig_cooldown=jnp.asarray(cfg.mig_cooldown, jnp.float32),
         mig_limit=jnp.asarray(cfg.mig_limit, jnp.int32),
-        pair_hops=jnp.asarray(pair_hops_np(rt.route_len, rt.n_cand,
-                                           cl.topo.n_nodes)),
         ctrl_fail_t=jnp.asarray(cfg.ctrl_fail_t, jnp.float32),
         ctrl_recover_t=jnp.asarray(cfg.ctrl_recover_t, jnp.float32),
         ctrl_failover_delay=jnp.asarray(cfg.failover_delay, jnp.float32),
@@ -461,6 +449,7 @@ def init_state_from_consts(c: EngineConsts, n_switches: int,
         pkt_rem=c.pkt_bits.astype(f),
         pkt_pair=jnp.full(n_p, -1, jnp.int32),
         pkt_cand=jnp.full(n_p, -1, jnp.int32),
+        pkt_links=jnp.full((n_p, c.routes.shape[-1]), -1, jnp.int32),
         pkt_start=jnp.full(n_p, jnp.nan, f),
         pkt_finish=jnp.full(n_p, jnp.nan, f),
         vm_load=jnp.zeros(c.vm_host.shape[0], jnp.int32),
@@ -606,7 +595,7 @@ def _apply_failures(c: EngineConsts, meta, pol, s: SimState, cache):
                       | ((s.pkt_state == WAITING) & (s.pkt_cand >= 0)))
         else:
             routed = p_active
-        links = _route_links(c, s, routed)
+        links = _route_links(s, routed)
         route_hit = routed & jnp.any(
             (links >= 0) & new_l[jnp.maximum(links, 0)], axis=-1)
 
@@ -846,12 +835,9 @@ def _admit_and_place(c: EngineConsts, meta, pol, aux, s: SimState):
     return s, placed, admit_now
 
 
-def _route_links(c: EngineConsts, s: SimState, mask: jnp.ndarray) -> jnp.ndarray:
+def _route_links(s: SimState, mask: jnp.ndarray) -> jnp.ndarray:
     """[N_P, H] link ids of each packet's chosen route (-1 where masked)."""
-    pair = jnp.maximum(s.pkt_pair, 0)
-    cand = jnp.maximum(s.pkt_cand, 0)
-    links = c.routes[pair, cand]
-    return jnp.where(mask[:, None], links, -1)
+    return jnp.where(mask[:, None], s.pkt_links, -1)
 
 
 NODE_OFFSET = 1 << 20  # pkt_src/dst_task >= NODE_OFFSET encodes a direct
@@ -876,8 +862,9 @@ def _pkt_endpoints(c: EngineConsts, meta, s: SimState):
 
 
 def _endpoint_cache(c: EngineConsts, meta, s: SimState):
-    """Per-packet (src*n_nodes+dst) pair index and reachability, derived
-    purely from the current task placement.  Placement changes on only a
+    """Per-packet (src*n_nodes+dst) pair index, the ``RouteEnds`` its
+    route is composed from, and reachability, derived purely from the
+    current task placement.  Placement changes on only a
     handful of steps (admissions, failure re-placements), so ``_step``
     keeps this in the while-loop carry and refreshes it under a
     ``lax.cond`` instead of re-resolving every event (DESIGN.md §8).
@@ -890,8 +877,9 @@ def _endpoint_cache(c: EngineConsts, meta, s: SimState):
     pair = (src_node * meta.n_nodes + dst_node).astype(jnp.int32)
     # unreachable pairs (no candidate route, different nodes) never
     # activate -> the engine reports a stall instead of free transfer
-    reachable = (c.n_cand[pair] > 0) | (src_node == dst_node)
-    return {"pair": pair, "reachable": reachable}
+    ends = route_ends(c, src_node, dst_node)
+    reachable = (ends.n_cand > 0) | (src_node == dst_node)
+    return {"pair": pair, "ends": ends, "reachable": reachable}
 
 
 def _activate(c: EngineConsts, meta, pol, aux, cache, s: SimState):
@@ -932,7 +920,7 @@ def _activate(c: EngineConsts, meta, pol, aux, cache, s: SimState):
                         s.task_state[jnp.maximum(gate, 0)] == DONE)
     admitted = s.job_admitted[jnp.maximum(c.pkt_job, 0)]
     p_ready = (s.pkt_state == WAITING) & admitted & gate_ok & c.pkt_valid
-    pair_all = cache["pair"]
+    pair_all, ends = cache["pair"], cache["ends"]
     p_ready = p_ready & cache["reachable"]
     if meta.has_failures:
         # a packet whose endpoint task was unplaced by a host failure must
@@ -950,9 +938,12 @@ def _activate(c: EngineConsts, meta, pol, aux, cache, s: SimState):
 
     link_bw = _effective_link_bw(c, meta, s)
 
-    def _apply_ready(s, cand, nc):
+    def _apply_ready(s, cand, nc, links=None):
         # commit the activation: only ready packets change, so a step with
         # an empty ready set leaves (s, nc) bit-identical
+        if links is None:
+            with jax.named_scope("route_choice"):
+                links = route_links(c, ends, cand)          # [P, H]
         if meta.has_failures:
             # a failure-reverted packet re-activates but keeps its FIRST
             # start: its measured duration includes the outage
@@ -964,6 +955,7 @@ def _activate(c: EngineConsts, meta, pol, aux, cache, s: SimState):
             pkt_state=jnp.where(p_ready, ACTIVE, s.pkt_state),
             pkt_pair=jnp.where(p_ready, pair_all, s.pkt_pair),
             pkt_cand=jnp.where(p_ready, cand, s.pkt_cand),
+            pkt_links=jnp.where(p_ready[:, None], links, s.pkt_links),
             pkt_start=jnp.where(p_ready, start_val, s.pkt_start)), nc
 
     routing_static = static_policy_value(pol["routing"])
@@ -973,7 +965,9 @@ def _activate(c: EngineConsts, meta, pol, aux, cache, s: SimState):
         # the channel counts bumped by one order-independent integer
         # scatter-add — commutative, so bit-identical to the sequential
         # pop order the dynamic path preserves.
-        cand = legacy_route_choice(c.n_cand[pair_all], aux["pkt_hash"])
+        with jax.named_scope("route_choice"):
+            cand = legacy_route_choice(ends.n_cand, aux["pkt_hash"])
+            links_all = route_links(c, ends, cand)  # [P, H]
         # channel bump over the ready set only — compacted pop-order scan
         # like the SDN branch minus the route choice (a whole-packet-axis
         # one-hot contraction moves ~100x more elements than the few ready
@@ -989,7 +983,6 @@ def _activate(c: EngineConsts, meta, pol, aux, cache, s: SimState):
         idx = jnp.arange(n_p, dtype=jnp.int32)
         n_ready = jnp.sum(p_ready.astype(jnp.int32))
         link_iota = jnp.arange(n_l, dtype=jnp.int32)
-        links_all = c.routes[pair_all, cand]  # [P, H]
         links_safe = jnp.where(links_all >= 0, links_all, -1)
 
         def bump_one(k, carry):
@@ -1002,7 +995,7 @@ def _activate(c: EngineConsts, meta, pol, aux, cache, s: SimState):
 
         nc, _ = jax.lax.fori_loop(0, n_ready, bump_one,
                                   (cache["nc"], jnp.int32(-1)))
-        s, nc = _apply_ready(s, cand, nc)
+        s, nc = _apply_ready(s, cand, nc, links_all)
     elif routing_static == ROUTE_SDN:
         # static SDN: the controller feedback loop stays sequential, but
         # the scan body is restructured to be scatter-free — under vmap an
@@ -1028,10 +1021,11 @@ def _activate(c: EngineConsts, meta, pol, aux, cache, s: SimState):
         def act_sdn(k, carry):
             ch, cand_seq, cursor = carry
             i = jnp.min(jnp.where(p_ready & (idx > cursor), idx, n_p))
-            pair = pair_all[jnp.minimum(i, n_p - 1)]
-            cand = sdn_route_choice(c.routes[pair], c.n_cand[pair],
-                                    link_bw, ch)
-            links = c.routes[pair, cand]  # [H]
+            with jax.named_scope("route_choice"):
+                ends_i = ends.at(jnp.minimum(i, n_p - 1))
+                routes_k = route_candidates(c, ends_i)
+                cand = sdn_route_choice(routes_k, ends_i.n_cand, link_bw, ch)
+                links = routes_k[cand]  # [H]
             bump = jnp.sum((links[:, None] == link_iota[None, :])
                            .astype(jnp.int32), axis=0)
             return ch + bump, \
@@ -1050,8 +1044,9 @@ def _activate(c: EngineConsts, meta, pol, aux, cache, s: SimState):
             # independently at random and keeps it (§5.2).  No channel
             # feedback -> one shot (the flow hash is loop-invariant,
             # precomputed in ``aux``).
-            legacy_cand = legacy_route_choice(c.n_cand[pair_all],
-                                              aux["pkt_hash"])
+            with jax.named_scope("route_choice"):
+                legacy_cand = legacy_route_choice(ends.n_cand,
+                                                  aux["pkt_hash"])
             n_ready = jnp.sum(p_ready.astype(jnp.int32))
             is_sdn = pol["routing"] == ROUTE_SDN
 
@@ -1068,13 +1063,14 @@ def _activate(c: EngineConsts, meta, pol, aux, cache, s: SimState):
                 ch, cand_all, mask = carry
                 i = jnp.argmax(mask).astype(jnp.int32)
                 mask = mask.at[i].set(False)
-                pair = pair_all[i]
-                cand = jnp.where(
-                    is_sdn,
-                    sdn_route_choice(c.routes[pair], c.n_cand[pair],
-                                     link_bw, ch),
-                    legacy_cand[i])
-                links = c.routes[pair, cand]
+                with jax.named_scope("route_choice"):
+                    ends_i = ends.at(i)
+                    routes_k = route_candidates(c, ends_i)
+                    cand = jnp.where(
+                        is_sdn,
+                        sdn_route_choice(routes_k, ends_i.n_cand, link_bw, ch),
+                        legacy_cand[i])
+                    links = routes_k[cand]
                 ch = ch.at[jnp.maximum(links, 0)].add(
                     (links >= 0).astype(jnp.int32))
                 return ch, cand_all.at[i].set(cand), mask
@@ -1087,7 +1083,7 @@ def _activate(c: EngineConsts, meta, pol, aux, cache, s: SimState):
                              lambda args: args, (s, cache["nc"]))
 
     p_active = s.pkt_state == ACTIVE
-    links = _route_links(c, s, p_active)
+    links = _route_links(s, p_active)
     return s, links, p_active, nc, link_bw
 
 
@@ -1277,25 +1273,31 @@ def _activate_ctrl(c: EngineConsts, meta, pol, aux, cache, s: SimState):
     idx = jnp.arange(n_p, dtype=jnp.int32)
     liota = jnp.arange(n_l, dtype=jnp.int32)
     n_pop = jnp.sum(pop.astype(jnp.int32))
-    legacy_cand = legacy_route_choice(c.n_cand[pair_all], aux["pkt_hash"])
+    ends = cache["ends"]
+    with jax.named_scope("route_choice"):
+        legacy_cand = legacy_route_choice(ends.n_cand, aux["pkt_hash"])
     is_sdn = pol["routing"] == ROUTE_SDN
     t_now = s.time
 
     def pop_one(k, carry):
-        (nc, pkt_state, pkt_pair, pkt_cand, pkt_start, pkt_ready_t,
-         pkt_wait, tbl, cursor) = carry
+        (nc, pkt_state, pkt_pair, pkt_cand, pkt_links, pkt_start,
+         pkt_ready_t, pkt_wait, tbl, cursor) = carry
         i = jnp.min(jnp.where(pop & (idx > cursor), idx, n_p))
         safe = jnp.minimum(i, n_p - 1)
         woken = p_wake[safe]
+        # a pre-routed packet (woken, or pinned by the proactive pass)
+        # keeps the route it holds
         pre_routed = pkt_cand[safe] >= 0
         pair = jnp.where(pre_routed, pkt_pair[safe], pair_all[safe])
-        cand = jnp.where(
-            pre_routed, pkt_cand[safe],
-            jnp.where(is_sdn,
-                      sdn_route_choice(c.routes[pair], c.n_cand[pair],
-                                       link_bw, nc),
-                      legacy_cand[safe]))
-        links = c.routes[pair, cand]                     # [H]
+        with jax.named_scope("route_choice"):
+            ends_i = ends.at(safe)
+            routes_k = route_candidates(c, ends_i)
+            new_cand = jnp.where(
+                is_sdn, sdn_route_choice(routes_k, ends_i.n_cand, link_bw, nc),
+                legacy_cand[safe])
+            cand = jnp.where(pre_routed, pkt_cand[safe], new_cand)
+            links = jnp.where(pre_routed, pkt_links[safe],
+                              routes_k[new_cand])        # [H]
         needs_ctrl = is_sdn & ~woken & c.ctrl_on
         ready, tbl = _ctrl_request(c, meta, pair, links, needs_ctrl,
                                    pre_routed & ~woken, t_now, tbl)
@@ -1307,6 +1309,7 @@ def _activate_ctrl(c: EngineConsts, meta, pol, aux, cache, s: SimState):
                               pkt_state)
         pkt_pair = jnp.where(oh, pair, pkt_pair)
         pkt_cand = jnp.where(oh, cand, pkt_cand)
+        pkt_links = jnp.where(oh[:, None], links, pkt_links)
         pkt_start = jnp.where(oh, start_i, pkt_start)
         pkt_ready_t = jnp.where(oh, jnp.where(act_now, _INF, ready),
                                 pkt_ready_t)
@@ -1315,21 +1318,21 @@ def _activate_ctrl(c: EngineConsts, meta, pol, aux, cache, s: SimState):
         bump = jnp.sum(((links[:, None] == liota[None, :])
                         & (links >= 0)[:, None]).astype(jnp.int32), axis=0)
         nc = nc + bump * act_now.astype(jnp.int32)
-        return (nc, pkt_state, pkt_pair, pkt_cand, pkt_start, pkt_ready_t,
-                pkt_wait, tbl, i)
+        return (nc, pkt_state, pkt_pair, pkt_cand, pkt_links, pkt_start,
+                pkt_ready_t, pkt_wait, tbl, i)
 
     carry0 = (cache["nc"], s.pkt_state, s.pkt_pair, s.pkt_cand,
-              s.pkt_start, s.pkt_ready_t, s.pkt_install_wait, _ctrl_tbl(s),
-              jnp.int32(-1))
-    (nc, pkt_state, pkt_pair, pkt_cand, pkt_start, pkt_ready_t, pkt_wait,
-     tbl, _) = jax.lax.fori_loop(0, n_pop, pop_one, carry0)
+              s.pkt_links, s.pkt_start, s.pkt_ready_t, s.pkt_install_wait,
+              _ctrl_tbl(s), jnp.int32(-1))
+    (nc, pkt_state, pkt_pair, pkt_cand, pkt_links, pkt_start, pkt_ready_t,
+     pkt_wait, tbl, _) = jax.lax.fori_loop(0, n_pop, pop_one, carry0)
     s = _with_ctrl_tbl(s._replace(
         pkt_state=pkt_state, pkt_pair=pkt_pair, pkt_cand=pkt_cand,
-        pkt_start=pkt_start, pkt_ready_t=pkt_ready_t,
+        pkt_links=pkt_links, pkt_start=pkt_start, pkt_ready_t=pkt_ready_t,
         pkt_install_wait=pkt_wait), tbl)
 
     p_active = s.pkt_state == ACTIVE
-    links = _route_links(c, s, p_active)
+    links = _route_links(s, p_active)
     return s, links, p_active, nc, link_bw
 
 
@@ -1361,29 +1364,33 @@ def _preinstall(c: EngineConsts, meta, pol, aux, cache, s: SimState,
     t_now = s.time
 
     def pre_one(k, carry):
-        pkt_pair, pkt_cand, tbl, snc, cursor = carry
+        pkt_pair, pkt_cand, pkt_links, tbl, snc, cursor = carry
         i = jnp.min(jnp.where(mask & (idx > cursor), idx, n_p))
         safe = jnp.minimum(i, n_p - 1)
         pair = pair_all[safe]
-        cand = sdn_route_choice(c.routes[pair], c.n_cand[pair], link_bw,
-                                snc)
-        links = c.routes[pair, cand]
+        with jax.named_scope("route_choice"):
+            ends_i = cache["ends"].at(safe)
+            routes_k = route_candidates(c, ends_i)
+            cand = sdn_route_choice(routes_k, ends_i.n_cand, link_bw, snc)
+            links = routes_k[cand]
         _, tbl = _ctrl_request(c, meta, pair, links, jnp.asarray(True),
                                jnp.asarray(False), t_now, tbl)
         oh = idx == i
         pkt_pair = jnp.where(oh, pair, pkt_pair)
         pkt_cand = jnp.where(oh, cand, pkt_cand)
+        pkt_links = jnp.where(oh[:, None], links, pkt_links)
         snc = snc + jnp.sum(((links[:, None] == liota[None, :])
                              & (links >= 0)[:, None]).astype(jnp.int32),
                             axis=0)
-        return pkt_pair, pkt_cand, tbl, snc, i
+        return pkt_pair, pkt_cand, pkt_links, tbl, snc, i
 
-    carry0 = (s.pkt_pair, s.pkt_cand, _ctrl_tbl(s), cache["nc"],
-              jnp.int32(-1))
-    pkt_pair, pkt_cand, tbl, _, _ = jax.lax.fori_loop(
+    carry0 = (s.pkt_pair, s.pkt_cand, s.pkt_links, _ctrl_tbl(s),
+              cache["nc"], jnp.int32(-1))
+    pkt_pair, pkt_cand, pkt_links, tbl, _, _ = jax.lax.fori_loop(
         0, jnp.sum(mask.astype(jnp.int32)), pre_one, carry0)
     return _with_ctrl_tbl(
-        s._replace(pkt_pair=pkt_pair, pkt_cand=pkt_cand), tbl)
+        s._replace(pkt_pair=pkt_pair, pkt_cand=pkt_cand,
+                   pkt_links=pkt_links), tbl)
 
 
 def _maybe_migrate(c: EngineConsts, meta, pol, s: SimState, cache):
@@ -1411,7 +1418,6 @@ def _maybe_migrate(c: EngineConsts, meta, pol, s: SimState, cache):
     n_vms = meta.n_vms
     n_t = s.task_vm.shape[0]
     n_p = s.pkt_state.shape[0]
-    n_pairs = c.pair_hops.shape[0]
 
     def attempt(args):
         s, nc0 = args
@@ -1426,8 +1432,10 @@ def _maybe_migrate(c: EngineConsts, meta, pol, s: SimState, cache):
         src_vm = ep_vm(c.pkt_src_task)
         dst_vm = ep_vm(c.pkt_dst_task)
         p_active = s.pkt_state == ACTIVE
+        pair = jnp.maximum(s.pkt_pair, 0)
         cost_p = jnp.where(
-            p_active, c.pair_hops[jnp.maximum(s.pkt_pair, 0)], 0
+            p_active, node_pair_hops(c, pair // meta.n_nodes,
+                                     pair % meta.n_nodes), 0
         ).astype(jnp.float32)
         cost = (jnp.sum(jnp.where(src_vm[:, None] == viota[None, :],
                                   cost_p[:, None], 0.0), axis=0)
@@ -1452,9 +1460,10 @@ def _maybe_migrate(c: EngineConsts, meta, pol, s: SimState, cache):
                             src_node[None, :])
         new_dst = jnp.where(mine_d[None, :], hiota[:, None],
                             dst_node[None, :])
-        est_pair = jnp.clip(new_src * meta.n_nodes + new_dst, 0,
-                            n_pairs - 1)
-        est = jnp.where(mine[None, :], c.pair_hops[est_pair], 0)
+        last = meta.n_nodes - 1
+        est = jnp.where(mine[None, :],
+                        node_pair_hops(c, jnp.clip(new_src, 0, last),
+                                       jnp.clip(new_dst, 0, last)), 0)
         est_cost = jnp.sum(est.astype(jnp.float32), axis=1)  # [n_h]
         host_live = hiota < c.n_hosts
         if meta.has_failures:
@@ -1480,7 +1489,7 @@ def _maybe_migrate(c: EngineConsts, meta, pol, s: SimState, cache):
                   | ((s.pkt_state == WAITING) & (s.pkt_cand >= 0)))
         hit_p = routed & ((src_vm == v) | (dst_vm == v)) & do
         hit_drop = hit_p & p_active
-        links = _route_links(c, s, hit_drop)
+        links = _route_links(s, hit_drop)
         pidx = jnp.arange(n_p, dtype=jnp.int32)
         liota = jnp.arange(meta.n_links, dtype=jnp.int32)
 
@@ -1963,8 +1972,7 @@ def _step(c: EngineConsts, meta, pol, aux, carry):
             i = jnp.min(jnp.where(p_done_now & (idx_p > cursor), idx_p,
                                   n_p_pad))                 # k < n_done -> real
             safe = jnp.minimum(i, n_p_pad - 1)
-            links_i = c.routes[jnp.maximum(s.pkt_pair[safe], 0),
-                               jnp.maximum(s.pkt_cand[safe], 0)]
+            links_i = s.pkt_links[safe]
             nc_c = nc_c - jnp.sum((links_i[:, None] == liota[None, :])
                                   .astype(jnp.int32), axis=0)
             feeds_i = c.pkt_feeds_task[safe]

@@ -52,6 +52,11 @@ def flows_setup(topo: Topology, flows: Sequence[Flow], *,
                 k_max: int = 8,
                 route_table: RouteTable | None = None) -> SimSetup:
     cluster = flows_cluster(topo)
+    routed = set(range(topo.n_hosts)) | {topo.storage(i)
+                                         for i in range(topo.n_storage)}
+    if any(f.src not in routed or f.dst not in routed for f in flows):
+        raise ValueError("flows run between hosts and storage nodes; "
+                         "the route table holds no switch endpoint")
     rt = route_table or build_route_table(topo, k_max=k_max)
     rounds = sorted({f.round for f in flows})
     r_index = {r: i for i, r in enumerate(rounds)}

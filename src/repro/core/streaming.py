@@ -349,6 +349,7 @@ def make_refill(meta):
             pkt_rem=jnp.where(pkt_m, c.pkt_bits, s.pkt_rem).astype(f),
             pkt_pair=jnp.where(pkt_m, -1, s.pkt_pair),
             pkt_cand=jnp.where(pkt_m, -1, s.pkt_cand),
+            pkt_links=jnp.where(pkt_m[:, None], -1, s.pkt_links),
             pkt_start=jnp.where(pkt_m, jnp.nan, s.pkt_start).astype(f),
             pkt_finish=jnp.where(pkt_m, jnp.nan, s.pkt_finish).astype(f),
             pkt_reroutes=jnp.where(pkt_m, 0, s.pkt_reroutes),

@@ -145,6 +145,26 @@ def _fat_tree(k: int = 4, seed: int = 0, n_jobs: int = 6) -> Scenario:
     )
 
 
+@register("al-fares-fat-tree")
+def _al_fares_fat_tree(k: int = 4, seed: int = 0, n_each: int = 1,
+                       split: int = 2, k_max: int = 16) -> Scenario:
+    """Al-Fares et al. (SIGCOMM 2008, §3) k-ary fat-tree — k^3/4 hosts,
+    (k/2)^2 equal-cost paths between pods, 1 Gbit/s links, the SAN on core
+    0 at 4 Gbit/s — running the paper's Table-3 job mix (``n_each`` of
+    each size class, 1 s apart) on Table-2 hosts with one VM each.  The
+    paper's Fig. 9 fabric is this construction at k = 4 (with doubled
+    core cables); ``split`` and ``k_max`` as ``paper-fabric`` takes them
+    (k_max = (k/2)^2 keeps every inter-pod path)."""
+    return Scenario(
+        name=f"al-fares-fat-tree-k{k}",
+        topology=lambda: fat_tree(k),
+        workload=lambda: paper_jobs(seed=seed, n_each=n_each),
+        description=f"Al-Fares {k}-ary fat-tree, Table-3 job mix",
+        split=split,
+        k_max=k_max,
+    )
+
+
 @register("leaf-spine")
 def _leaf_spine(n_spine: int = 4, n_leaf: int = 4, hosts_per_leaf: int = 4,
                 seed: int = 0, n_jobs: int = 6) -> Scenario:

@@ -35,14 +35,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.engine import (EngineConsts, NODE_OFFSET, UNREACHABLE_HOPS,
-                           default_max_steps, job_n_tasks_np,
+from ..core.engine import (EngineConsts, NODE_OFFSET, default_max_steps, job_n_tasks_np,
                            job_valid_mask, task_rank_in_job_np)
 from ..core.ctrlplane import no_ctrl
 from ..core.failures import no_degradation, no_failures
 from ..core.mapreduce import SimSetup
 from ..core.policies import as_policy_arrays, policy_field_names
 from ..core.report import energy_report, job_report_consts
+from ..core.routing import UNREACHABLE_HOPS
 from ..core.simmeta import SimMeta
 
 
@@ -60,7 +60,8 @@ def _pack_one(setup: SimSetup, dims: Dict[str, int]) -> Dict[str, np.ndarray]:
     deg = setup.degradation or no_degradation(topo.n_hosts, topo.n_links)
     cfg = setup.ctrl or no_ctrl()
     H, SW = dims["n_hosts"], dims["n_switches"]
-    Nn, L, K, HP = dims["n_nodes"], dims["n_links"], dims["k_max"], dims["max_hops"]
+    L, K, HP = dims["n_links"], dims["k_max"], dims["max_hops"]
+    A = dims["n_att"]
     n_h, n_sw = topo.n_hosts, topo.n_switches
 
     def node_map(ids):
@@ -78,20 +79,19 @@ def _pack_one(setup: SimSetup, dims: Dict[str, int]) -> Dict[str, np.ndarray]:
                         NODE_OFFSET + node_map(a - NODE_OFFSET),
                         a).astype(np.int32)
 
-    # routes: scatter each (src, dst) pair into the renumbered pair index
-    m_ids = node_map(np.arange(topo.n_nodes))
-    new_pair = (m_ids[:, None].astype(np.int64) * Nn + m_ids[None, :]).reshape(-1)
-    routes = np.full((Nn * Nn, K, HP), -1, np.int32)
-    routes[new_pair, : rt.k_max, : rt.max_hops] = rt.routes
-    n_cand = np.zeros((Nn * Nn,), np.int32)
-    n_cand[new_pair] = rt.n_cand
-    # candidate-0 hop counts at the padded pair layout (DESIGN.md §10):
-    # pad pairs are unreachable, the padded diagonal stays 0
-    pair_hops = np.full((Nn * Nn,), UNREACHABLE_HOPS, np.int32)
-    pair_hops[new_pair] = np.where(rt.n_cand > 0, rt.route_len[:, 0],
-                                   UNREACHABLE_HOPS).astype(np.int32)
-    diag = np.arange(Nn, dtype=np.int64)
-    pair_hops[diag * Nn + diag] = 0
+    # routes: attachment pairs keep their indices (pad attachments route
+    # nowhere); the node maps move to the renumbered node ids, pad nodes
+    # are no endpoint, and link ids are unchanged (links pad by appending)
+    n_a = rt.n_cand.shape[0]
+    routes = np.full((A, A, K, HP), -1, np.int32)
+    routes[:n_a, :n_a, : rt.k_max, : rt.max_hops] = rt.routes
+    n_cand = np.zeros((A, A), np.int32)
+    n_cand[:n_a, :n_a] = rt.n_cand
+    pair_hops = np.full((A, A), UNREACHABLE_HOPS, np.int32)
+    pair_hops[:n_a, :n_a] = rt.pair_hops
+    node_route = np.full((dims["n_nodes"], 3), -1, np.int32)
+    node_route[node_map(np.arange(topo.n_nodes))] = \
+        rt.device_arrays()["node_route"]
 
     # failure schedule (DESIGN.md §7): pad hosts/links never fail; the
     # concatenated breakpoint tensor (DESIGN.md §8) is rebuilt from the
@@ -142,6 +142,7 @@ def _pack_one(setup: SimSetup, dims: Dict[str, int]) -> Dict[str, np.ndarray]:
     return {
         "routes": routes,
         "n_cand": n_cand,
+        "node_route": node_route,
         "link_bw": _pad1(np.asarray(topo.link_bw, np.float32), L, 0.0),
         "link_src": _pad1(node_map(topo.link_src), L, 0),
         "link_dst": _pad1(node_map(topo.link_dst), L, 0),
@@ -241,6 +242,7 @@ def pack_setups(setups: Sequence[SimSetup]
         "n_links": max(s.cluster.topo.n_links for s in setups),
         "k_max": max(s.route_table.k_max for s in setups),
         "max_hops": max(s.route_table.max_hops for s in setups),
+        "n_att": max(s.route_table.n_cand.shape[0] for s in setups),
         "n_jobs": max(s.n_jobs for s in setups),
         "n_tasks": max(s.n_tasks for s in setups),
         "n_packets": max(s.n_packets for s in setups),
@@ -356,8 +358,8 @@ def sweep_grid(scenarios: Sequence[Tuple[str, SimSetup]],
     flat replica-major ``SweepResult`` shape.
 
     The Experiment path keeps the nested-vmap structure — scenarios outer,
-    policies inner — so the dense consts tensors (routes is [n_nodes², K, H]
-    per scenario) broadcast across the policy axis instead of being
+    policies inner — so the dense consts tensors (routes is
+    [n_att, n_att, K, H] per scenario) broadcast across the policy axis instead of being
     materialized P times."""
     from ..api import Experiment
     res = Experiment(scenarios=list(scenarios),

@@ -32,7 +32,9 @@ from repro.core.policies import (JOBSEL_FCFS, JOBSEL_PRIORITY, JOBSEL_SJF,
                                  RECOVERY_RESTART, RECOVERY_RESUME,
                                  ROUTE_LEGACY, ROUTE_SDN, TRAFFIC_FAIRSHARE,
                                  TRAFFIC_WATERFILL)
-from repro.core.routing import choose_route, flow_hash_u32
+from repro.core.routing import (choose_route, flow_hash_u32,
+                                route_candidates, route_ends,
+                                route_links)
 from repro.core.simmeta import SimMeta
 from repro.api import runners
 from repro.scenarios import get_scenario, list_scenarios
@@ -51,10 +53,11 @@ def _ref_link_bw(c, meta, s):
     return c.link_bw
 
 
-def _ref_route_links(c, s, mask):
+def _ref_route_links(c, meta, s, mask):
     pair = jnp.maximum(s.pkt_pair, 0)
     cand = jnp.maximum(s.pkt_cand, 0)
-    links = c.routes[pair, cand]
+    ends = route_ends(c, pair // meta.n_nodes, pair % meta.n_nodes)
+    links = route_links(c, ends, cand)
     return jnp.where(mask[:, None], links, -1)
 
 
@@ -70,7 +73,7 @@ def _ref_endpoints(c, s):
     return node_of(c.pkt_src_task), node_of(c.pkt_dst_task)
 
 
-def _ref_apply_failures(c, pol, s):
+def _ref_apply_failures(c, meta, pol, s):
     t = s.time
     host_dead = (c.host_fail_t <= t) & (t < c.host_recover_t)
     link_dead = (c.link_fail_t <= t) & (t < c.link_recover_t)
@@ -81,7 +84,7 @@ def _ref_apply_failures(c, pol, s):
     n_hosts_pad = c.host_fail_t.shape[0]
     src_node, dst_node = _ref_endpoints(c, s)
     p_active = s.pkt_state == ACTIVE
-    links = _ref_route_links(c, s, p_active)
+    links = _ref_route_links(c, meta, s, p_active)
     route_hit = p_active & jnp.any(
         (links >= 0) & new_l[jnp.maximum(links, 0)], axis=-1)
 
@@ -210,7 +213,8 @@ def _ref_activate(c, meta, pol, s):
     src_node, dst_node = _ref_endpoints(c, s)
     n_nodes = meta.n_nodes
     pair_all = (src_node * n_nodes + dst_node).astype(jnp.int32)
-    reachable = (c.n_cand[pair_all] > 0) | (src_node == dst_node)
+    reachable = ((route_ends(c, src_node, dst_node).n_cand > 0)
+                 | (src_node == dst_node))
     p_ready = p_ready & reachable
     if meta.has_failures:
         n_tasks = s.task_vm.shape[0]
@@ -226,18 +230,20 @@ def _ref_activate(c, meta, pol, s):
 
     link_bw = _ref_link_bw(c, meta, s)
     ch0 = fairshare.channel_counts(
-        _ref_route_links(c, s, s.pkt_state == ACTIVE),
+        _ref_route_links(c, meta, s, s.pkt_state == ACTIVE),
         s.pkt_state == ACTIVE, meta.n_links)
 
     def act_one(i, carry):
-        pkt_state, pkt_pair, pkt_cand, pkt_start, ch = carry
+        pkt_state, pkt_pair, pkt_cand, pkt_links, pkt_start, ch = carry
         ready = p_ready[i]
         pair = (src_node[i] * n_nodes + dst_node[i]).astype(jnp.int32)
         fh = flow_hash_u32(c.pkt_src_task[i] + 1, c.pkt_dst_task[i] + 1,
                            pol["seed"])
-        cand = choose_route(pol["routing"], c.routes[pair], c.n_cand[pair],
+        ends = route_ends(c, src_node[i], dst_node[i])
+        routes_k = route_candidates(c, ends)
+        cand = choose_route(pol["routing"], routes_k, ends.n_cand,
                             link_bw, ch, fh)
-        links = c.routes[pair, cand]
+        links = routes_k[cand]
         valid = links >= 0
         ch_new = ch.at[jnp.maximum(links, 0)].add(valid.astype(jnp.int32))
         if meta.has_failures:
@@ -249,20 +255,23 @@ def _ref_activate(c, meta, pol, s):
             jnp.where(ready, pkt_state.at[i].set(ACTIVE), pkt_state),
             jnp.where(ready, pkt_pair.at[i].set(pair), pkt_pair),
             jnp.where(ready, pkt_cand.at[i].set(cand), pkt_cand),
+            jnp.where(ready, pkt_links.at[i].set(links), pkt_links),
             jnp.where(ready, pkt_start.at[i].set(start_val), pkt_start),
             jnp.where(ready, ch_new, ch),
         )
 
-    pkt_state, pkt_pair, pkt_cand, pkt_start, _ = jax.lax.fori_loop(
-        0, s.pkt_state.shape[0], act_one,
-        (s.pkt_state, s.pkt_pair, s.pkt_cand, s.pkt_start, ch0))
+    pkt_state, pkt_pair, pkt_cand, pkt_links, pkt_start, _ = \
+        jax.lax.fori_loop(0, s.pkt_state.shape[0], act_one,
+                          (s.pkt_state, s.pkt_pair, s.pkt_cand, s.pkt_links,
+                           s.pkt_start, ch0))
     return s._replace(pkt_state=pkt_state, pkt_pair=pkt_pair,
-                      pkt_cand=pkt_cand, pkt_start=pkt_start)
+                      pkt_cand=pkt_cand, pkt_links=pkt_links,
+                      pkt_start=pkt_start)
 
 
 def _ref_rates(c, meta, pol, s):
     p_active = s.pkt_state == ACTIVE
-    links = _ref_route_links(c, s, p_active)
+    links = _ref_route_links(c, meta, s, p_active)
     pkt_rate = fairshare.rates(pol["traffic"], links, p_active,
                                _ref_link_bw(c, meta, s), meta.intra_bw)
     t_active = s.task_state == ACTIVE
@@ -289,7 +298,7 @@ def _ref_finished(c, meta, s):
 def _ref_step(c, meta, pol, s):
     from repro.core.energy import host_power, switch_power
     if meta.has_failures:
-        s = _ref_apply_failures(c, pol, s)
+        s = _ref_apply_failures(c, meta, pol, s)
     s = _ref_admit_and_place(c, meta, pol, s)
     s = _ref_activate(c, meta, pol, s)
     pkt_rate, task_rate, links, p_active, t_active = _ref_rates(
@@ -417,6 +426,7 @@ def ref_simulator(meta):
 NO_FAILURE_SCENARIOS = [
     ("paper-fabric", dict(split=1)),
     ("fat-tree", dict(n_jobs=4)),
+    ("al-fares-fat-tree", dict(n_each=1, split=1, k_max=4)),
     ("leaf-spine", dict(n_jobs=4)),
     ("canonical-tree", dict(n_jobs=4)),
     ("leaf-spine-xl", dict(n_spine=2, n_leaf=2, hosts_per_leaf=2, n_jobs=4,

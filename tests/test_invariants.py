@@ -20,6 +20,7 @@ from repro.scenarios.sweep import pack_setups, policy_arrays
 SCENARIOS = [
     ("paper-fabric", dict(split=1)),
     ("fat-tree", dict(n_jobs=4)),
+    ("al-fares-fat-tree", dict(n_each=1, split=1, k_max=4)),
     ("leaf-spine", dict(n_jobs=4)),
     ("canonical-tree", dict(n_jobs=4)),
     ("leaf-spine-xl", dict(n_spine=2, n_leaf=2, hosts_per_leaf=2, n_jobs=4,

@@ -48,14 +48,21 @@ def test_leaf_spine_counts_and_bisection_bw():
     assert np.isclose(topo.link_bw[cut].sum(), (l // 2) * s * GBPS)
 
 
+def _endpoint_n_cand(topo, rt):
+    """Candidate counts between every pair of hosts and storage nodes."""
+    ends = np.r_[np.arange(topo.n_hosts),
+                 topo.storage(0) + np.arange(topo.n_storage)]
+    return rt.n_cand_between(ends[:, None], ends[None, :])
+
+
 def test_canonical_tree_structure_and_unique_routes():
     topo = canonical_tree(depth=3, fanout=2, hosts_per_edge=2)
     assert topo.n_switches == 1 + 2 + 4
     assert topo.n_hosts == 4 * 2
-    # a tree has exactly one route between any two nodes
+    # a tree has exactly one route between any two endpoints
     rt = build_route_table(topo, k_max=4)
-    nc = rt.n_cand.reshape(topo.n_nodes, topo.n_nodes)
-    off = ~np.eye(topo.n_nodes, dtype=bool)
+    nc = _endpoint_n_cand(topo, rt)
+    off = ~np.eye(nc.shape[0], dtype=bool)
     assert np.all(nc[off] == 1)
 
 
@@ -69,20 +76,20 @@ def test_all_nodes_reachable_and_candidates_symmetric(topo_fn):
     dist = hop_distances_np(topo.hop_matrix())
     assert np.all(np.isfinite(dist)), "fabric must be connected"
     rt = build_route_table(topo, k_max=16)
-    nc = rt.n_cand.reshape(topo.n_nodes, topo.n_nodes)
+    nc = _endpoint_n_cand(topo, rt)
     # these fabrics are symmetric graphs: equal-hop route count must be too
     assert np.array_equal(nc, nc.T)
+    assert np.all(nc[~np.eye(nc.shape[0], dtype=bool)] > 0)
 
 
 def test_leaf_spine_route_diversity_equals_spine_count():
     s = 3
     topo = leaf_spine(n_spine=s, n_leaf=2, hosts_per_leaf=2)
     rt = build_route_table(topo, k_max=8)
-    nc = rt.n_cand.reshape(topo.n_nodes, topo.n_nodes)
     # inter-leaf host pair: one equal-hop route per spine
-    assert nc[0, topo.n_hosts - 1] == s
+    assert rt.n_cand_between(0, topo.n_hosts - 1) == s
     # same-leaf host pair: single route via the shared leaf
-    assert nc[0, 1] == 1
+    assert rt.n_cand_between(0, 1) == 1
 
 
 # ---------------------------------------------------------------------------

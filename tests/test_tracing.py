@@ -319,3 +319,39 @@ def test_uniform_eq3_batch_has_no_waterfill_loop(compiled_loops):
     assert rates_ops(uniform, "while") == []
     assert "/rates/" not in "".join(
         line for line in uniform.splitlines() if "scatter" in line)
+
+
+@pytest.mark.parametrize("program", ["policy_batch", "policy_batch_uniform",
+                                     "fleet_chunk"])
+def test_route_choice_sits_under_activate(compiled_loops, program):
+    """Every operation of the route choice and route-link composition
+    (``route_choice``) is nested in the ``activate`` phase, so the phase
+    readers keep counting it there."""
+    hlo, _ = compiled_loops[program]
+    names = set(re.findall(r'op_name="([^"]*)"', hlo))
+    route = [n for n in names if "route_choice" in n]
+    assert route
+    for n in route:
+        assert op_phase(n) == "activate", n
+        parts = n.split("/")
+        at = next(i for i, p in enumerate(parts) if "route_choice" in p)
+        assert any("activate" in p for p in parts[:at]), n
+
+
+@pytest.mark.parametrize("k_max, truncated", [(8, 48), (16, 0)])
+def test_route_table_counters_on_the_paper_fabric(k_max, truncated):
+    """The Fig. 9 fabric: 8 edge switches and core 1 (the SAN's) are the
+    attachments.  Per ordered pair of edges in different pods 2 aggs x 2
+    cores x 2 x 2 parallel cables = 16 routes (48 pairs), in one pod 2
+    (8 pairs); core 1 to an edge and back 2 each (16 pairs)."""
+    from repro.core.routing import build_route_table
+    from repro.core.topology import paper_fat_tree
+    topo = paper_fat_tree()
+    rt = build_route_table(topo, k_max=k_max)
+    assert rt.n_pairs == 9 * 9
+    assert rt.n_truncated == truncated
+    assert rt.n_enumerated == (48 * min(16, k_max) + 8 * 2 + 16 * 2)
+    h = rt.max_hops
+    assert h == 6
+    assert rt.device_bytes == (4 * 81 * k_max * h + 2 * 4 * 81
+                               + 3 * 4 * topo.n_nodes)
