@@ -151,6 +151,10 @@ class FleetStats:
     refills: int = 0     # lanes recycled mid-cohort
     devices: int = 1     # fleet-mesh size (1 = no shard_map)
     width: int = 0       # lanes per cohort (after device round-up)
+    # engine steps the chunks ran (summed over devices), and how many of
+    # them ran the failure transitions for some lane (DESIGN.md §9)
+    chunk_steps: int = 0
+    fail_steps: int = 0
     # every array run_fleet moves between host and device, and its bytes
     # (shape x itemsize): done flags, retire fetches, masks and the numpy
     # arguments a chunk, init or refill call uploads
@@ -190,7 +194,8 @@ def _chunk_program(meta: SimMeta, sig: Tuple[int, ...], chunk_steps: int,
 
         def counted(consts, pol, carry):
             runners.note_trace()
-            return chunk(consts, pol, carry)
+            carry, counts = chunk(consts, pol, carry)
+            return carry, counts[None]     # [devices, 2] across the mesh
 
         fn = counted
         if n_dev > 1:
@@ -203,7 +208,8 @@ def _chunk_program(meta: SimMeta, sig: Tuple[int, ...], chunk_steps: int,
             # is a shard-local jnp.all over its own done flags)
             fn = jax.shard_map(counted, mesh=mesh,
                                in_specs=(P(), P("fleet"), P("fleet")),
-                               out_specs=P("fleet"), check_vma=False)
+                               out_specs=(P("fleet"), P("fleet")),
+                               check_vma=False)
         # donating the carry lets XLA alias it through the while loop
         # (jaxcheck:donation); the caller never reads a carry it passed in
         return jax.jit(fn, donate_argnums=runners.DONATE_ARGNUMS)
@@ -325,10 +331,14 @@ def run_fleet(exp, width: int = 32, chunk_steps: int = 32,
                               * (meta.max_steps // chunk_steps + 2))
                 chunks = 0
                 pol_lane = _lane_policies(pol_np, sched)
+                # the chunks' step counts, summed on the device and
+                # fetched once per cohort: no extra sync per chunk
+                counts = None
             while sched.active:
                 with TraceAnnotation("repro.fleet.chunk"):
                     stats.count_uploads(pol_lane)
-                    carry = chunk(consts_s, pol_lane, carry)
+                    carry, n = chunk(consts_s, pol_lane, carry)
+                    counts = n if counts is None else counts + n
                 chunks += 1
                 stats.chunks += 1
                 if chunks > max_chunks:
@@ -367,6 +377,9 @@ def run_fleet(exp, width: int = 32, chunk_steps: int = 32,
                         carry = _refill_program(meta, W)(mask, consts_s,
                                                          carry)
                         pol_lane = _lane_policies(pol_np, sched)
+            steps, fails = stats.fetch(counts).sum(0)
+            stats.chunk_steps += int(steps)
+            stats.fail_steps += int(fails)
 
     states = state_cls(*out)   # the serial runner's [S, P, ...] grid
     if S == 1:   # Results keeps a scenario axis on consts

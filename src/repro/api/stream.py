@@ -45,6 +45,8 @@ class StreamStats:
     retired: int = 0     # job completions recorded, all lanes
     trace_len: int = 0   # arrivals materialized below the horizon
     slots: int = 0       # ring capacity (jobs resident per lane)
+    chunk_steps: int = 0  # engine steps the chunks ran
+    fail_steps: int = 0   # ... of which ran the failure transitions
 
 
 def _percentile(a: np.ndarray, q: float) -> float:
@@ -301,7 +303,7 @@ def run_stream(exp, arrivals, horizon: float, *, warmup: float = 0.0,
 
         chunks = 0
         while any(lane_live(li) for li in range(W)):
-            carry = chunk(consts_dev, pol_lane, carry)
+            carry, counts = chunk(consts_dev, pol_lane, carry)
             chunks += 1
             stats.chunks += 1
             if chunks > max_chunks:
@@ -310,9 +312,12 @@ def run_stream(exp, arrivals, horizon: float, *, warmup: float = 0.0,
                     "without draining — engine not making progress")
             s = carry[0]
             (done, t_arr, stalled, out_done, done_t, admit_t,
-             he, se, hb) = jax.device_get(
+             he, se, hb, counts) = jax.device_get(
                 (carry[2], s.time, s.stalled, s.job_out_done, s.job_done_t,
-                 s.job_admit_t, s.host_energy, s.switch_energy, s.host_busy))
+                 s.job_admit_t, s.host_energy, s.switch_energy, s.host_busy,
+                 counts))
+            stats.chunk_steps += int(counts[0])
+            stats.fail_steps += int(counts[1])
             job_m = np.zeros((W, n_slots), bool)
             task_m = np.zeros((W, n_slots * T), bool)
             pkt_m = np.zeros((W, n_slots * Pk), bool)

@@ -544,44 +544,28 @@ def _vm_host(c: EngineConsts, meta, s: SimState) -> jnp.ndarray:
     return c.vm_host
 
 
-def _apply_failures(c: EngineConsts, meta, pol, s: SimState, cache):
-    """Fire every fail/recover transition whose instant has been reached.
-
-    Failure instants join the dt horizon (``_step``), so ``s.time`` lands
-    exactly on each one; here — at the top of the next iteration — the dead
-    masks are recomputed from the schedule and the DELTA vs the previous
-    masks drives the one-shot transitions (DESIGN.md §7):
-
-      * WAITING/ACTIVE tasks on a newly-dead host revert to WAITING and
-        unplace (``task_vm=-1``) — YARN re-execution on heartbeat loss;
-        under ``recovery=restart`` their progress is lost, under ``resume``
-        (beyond-paper checkpointing) ``task_rem`` survives.
-      * In-flight packets whose chosen route crosses a newly-dead link
-        revert to WAITING for re-routing (bits already delivered survive:
-        the stream resumes on the new route).
-      * In-flight packets whose src/dst HOST newly died revert too — the
-        connection died with the endpoint — and retransmit from scratch
-        under ``restart``.
-
-    DONE work is never reverted (completed outputs are durable — the SAN
-    holds T3 results, map outputs are re-fetchable); recovery instants need
-    no transition, the masks simply clear.
-
-    The revert scans (per-packet route intersection, per-task host lookup)
-    only matter on the handful of steps where something newly died, so
-    they sit behind a ``lax.cond`` on the death delta — recovery-only and
-    steady-state steps just refresh the dead masks (DESIGN.md §8).
-    """
+def _refresh_failures(c: EngineConsts, s: SimState):
+    """Recompute the dead masks from the schedule at ``s.time`` and store
+    them; -> ``(s, new_h, new_l)``, the hosts and links that died since
+    the masks were last refreshed (DESIGN.md §7)."""
     t = s.time
     host_dead = (c.host_fail_t <= t) & (t < c.host_recover_t)
     link_dead = (c.link_fail_t <= t) & (t < c.link_recover_t)
     new_h = host_dead & ~s.host_dead
     new_l = link_dead & ~s.link_dead
-    s = s._replace(host_dead=host_dead, link_dead=link_dead)
-    restart = pol["recovery"] == RECOVERY_RESTART
+    return (s._replace(host_dead=host_dead, link_dead=link_dead),
+            new_h, new_l)
 
-    def transitions(args):
-        s, nc0 = args
+
+def _fail_transitions(c: EngineConsts, meta, pol, s: SimState, nc0,
+                      new_h, new_l):
+    """The one-shot transitions of the hosts ``new_h`` and links ``new_l``
+    that just died; -> ``(s, nc)``.  With both masks all-false every hit
+    mask is false and the channel-drop loop has zero trips, so the result
+    is ``(s, nc0)`` bit for bit — which lets the fleet chunk run it for
+    every lane once any lane needs it (DESIGN.md §9)."""
+    with jax.named_scope("fail_transitions"):
+        restart = pol["recovery"] == RECOVERY_RESTART
         # packets first: endpoints must resolve against the ACTIVATION-time
         # placement, i.e. before any task unplaces below.
         n_hosts_pad = c.host_fail_t.shape[0]
@@ -631,8 +615,9 @@ def _apply_failures(c: EngineConsts, meta, pol, s: SimState, cache):
         task_rem = jnp.where(hit_t & restart, c.task_mi.astype(jnp.float32),
                              s.task_rem)
         task_start = jnp.where(hit_t, jnp.nan, s.task_start)
-        # one-hot contraction, not a scatter: this runs EVERY step under a
-        # vmapped cond, and batched scatters serialize per lane
+        # one-hot contraction, not a scatter: under a per-lane cond (the
+        # batched runners) this runs every step, and batched scatters
+        # serialize per lane
         vm_iota = jnp.arange(s.vm_load.shape[0], dtype=jnp.int32)
         vm_load = s.vm_load - jnp.sum(
             (vm_safe[:, None] == vm_iota[None, :]) & hit_t[:, None],
@@ -650,7 +635,7 @@ def _apply_failures(c: EngineConsts, meta, pol, s: SimState, cache):
         # length = the revert count, zero on recovery-only steps).  The
         # carried nc is maintained exactly by activation/completion, so
         # this equals a from-scratch recount bit-for-bit — but a recount's
-        # [n_p, H, n_links] one-hot runs EVERY step under a vmapped cond
+        # [n_p, H, n_links] one-hot runs every step under a per-lane cond
         # (DESIGN.md §9) and dominated the failure-grid fleet profile.
         n_p = hit_p.shape[0]
         pidx = jnp.arange(n_p, dtype=jnp.int32)
@@ -666,10 +651,49 @@ def _apply_failures(c: EngineConsts, meta, pol, s: SimState, cache):
 
         nc, _ = jax.lax.fori_loop(0, jnp.sum(hit_drop.astype(jnp.int32)),
                                   drop_one, (nc0, jnp.int32(-1)))
-        return s, nc
+    return s, nc
 
-    s, nc = jax.lax.cond(jnp.any(new_h) | jnp.any(new_l), transitions,
-                         lambda args: args, (s, cache["nc"]))
+
+def _apply_failures(c: EngineConsts, meta, pol, s: SimState, cache,
+                    fire=None):
+    """Fire every fail/recover transition whose instant has been reached.
+
+    Failure instants join the dt horizon (``_step``), so ``s.time`` lands
+    exactly on each one; here — at the top of the next iteration — the dead
+    masks are recomputed from the schedule and the DELTA vs the previous
+    masks drives the one-shot transitions (DESIGN.md §7):
+
+      * WAITING/ACTIVE tasks on a newly-dead host revert to WAITING and
+        unplace (``task_vm=-1``) — YARN re-execution on heartbeat loss;
+        under ``recovery=restart`` their progress is lost, under ``resume``
+        (beyond-paper checkpointing) ``task_rem`` survives.
+      * In-flight packets whose chosen route crosses a newly-dead link
+        revert to WAITING for re-routing (bits already delivered survive:
+        the stream resumes on the new route).
+      * In-flight packets whose src/dst HOST newly died revert too — the
+        connection died with the endpoint — and retransmit from scratch
+        under ``restart``.
+
+    DONE work is never reverted (completed outputs are durable — the SAN
+    holds T3 results, map outputs are re-fetchable); recovery instants need
+    no transition, the masks simply clear.
+
+    The revert scans (``_fail_transitions``) only matter on the handful of
+    steps where something newly died, so they sit behind a ``lax.cond`` on
+    the death delta — recovery-only and steady-state steps just refresh
+    the dead masks (``_refresh_failures``, DESIGN.md §8).  The serial
+    runner's cond really branches; under the batched runners' vmap it
+    lowers to a select.  The fleet chunk passes ``fire``, the predicate
+    decided once for all its lanes outside its lane vmap, so its cond
+    stays a cond (``make_fleet_chunk``, DESIGN.md §9).
+    """
+    s, new_h, new_l = _refresh_failures(c, s)
+    if fire is None:
+        fire = jnp.any(new_h) | jnp.any(new_l)
+    s, nc = jax.lax.cond(
+        fire,
+        lambda a: _fail_transitions(c, meta, pol, *a, new_h, new_l),
+        lambda a: a, (s, cache["nc"]))
     return s, {**cache, "nc": nc}
 
 
@@ -1736,11 +1760,15 @@ def _step(c: EngineConsts, meta, pol, aux, carry):
     ``jax.named_scope`` phases, which XLA keeps in each op's ``op_name``,
     so a device trace splits the step's time by phase: ``admit_place``,
     ``activate``, ``chaos`` (failures, degradation, speculation),
-    ``rates``, ``advance`` (dt-min, energy, clock) and ``complete``."""
+    ``rates``, ``advance`` (dt-min, energy, clock) and ``complete``.
+
+    ``aux["fail_fire"]``, where present, is the failure transitions'
+    predicate decided outside a lane vmap (``make_fleet_chunk``)."""
     s, cache = carry
     if meta.has_failures:
         with jax.named_scope("chaos"):
-            s, cache = _apply_failures(c, meta, pol, s, cache)
+            s, cache = _apply_failures(c, meta, pol, s, cache,
+                                       aux.get("fail_fire"))
     with jax.named_scope("admit_place"):
         s, placed, admit_now = _admit_and_place(c, meta, pol, aux, s)
         if meta.has_ctrl:
@@ -2143,8 +2171,10 @@ def init_fleet_carry(consts: EngineConsts, meta, width: int):
 def make_fleet_chunk(meta, static_pol=None, chunk_steps: int = 32,
                      consts_axes=None):
     """Build the fleet's K-step cohort stepper (DESIGN.md §9):
-    ``chunk(consts, pol, carry) -> carry`` advancing every live lane up to
-    ``chunk_steps`` events, early-exiting when the whole cohort finishes.
+    ``chunk(consts, pol, carry) -> (carry, counts)`` advancing every live
+    lane up to ``chunk_steps`` events, early-exiting when the whole cohort
+    finishes.  ``counts`` is int32 ``[2]``: the chunk steps run, and how
+    many of them took the failure transitions (always 0 without failures).
 
     ``consts_axes`` (default None: one consts shared by every lane) is a
     vmap in_axes pytree over ``EngineConsts`` — the streaming ring
@@ -2161,19 +2191,31 @@ def make_fleet_chunk(meta, static_pol=None, chunk_steps: int = 32,
     ``fairshare.rates`` specialize their dispatch instead of executing
     both branches of a batched ``lax.cond`` (the batch wall).
 
+    With failures the failure transitions' predicate is decided here,
+    once per step for the cohort, outside the lane vmap, and passed into
+    ``_step`` as ``aux["fail_fire"]``: "some live lane has a new death".
+    Inside the vmap a per-lane predicate would turn the transitions'
+    ``lax.cond`` into a select running them for every lane on every
+    step; an unbatched one keeps it a cond.  A lane without a new death
+    gets the identity from them, so results are unchanged.  Done lanes
+    are masked out: a lane that finished exactly on a fail instant would
+    otherwise report a "new" death on each of its frozen pseudo-steps,
+    and its outputs are discarded.
+
     The caller jits (and on a multi-device mesh, shard_maps) the result;
     between chunk invocations the fleet scheduler retires finished lanes,
     compacts, and refills from its pending queue, so no lane runs more
     than ``chunk_steps - 1`` wasted events past its own finish."""
     meta = SimMeta.coerce(meta)
     static_pol = dict(static_pol or {})
+    hoist = meta.has_failures
 
     def lane_step(consts, pol_lane, aux, sc):
         pol = {**pol_lane, **static_pol}
         s, cache = _step(consts, meta, pol, aux, sc)
         return s, cache, _finished(consts, meta, s)
 
-    vstep = jax.vmap(lane_step, in_axes=(consts_axes, 0, 0, 0))
+    vrefresh = jax.vmap(_refresh_failures, in_axes=(consts_axes, 0))
 
     def chunk(consts, pol, carry):
         # loop-invariant per-lane tensors hoisted OUT of the while loop,
@@ -2186,14 +2228,26 @@ def make_fleet_chunk(meta, static_pol=None, chunk_steps: int = 32,
             vaux = jax.vmap(
                 lambda c_, p: _make_aux(c_, {**p, **static_pol}),
                 in_axes=(consts_axes, 0))(consts, pol)
+        aux_axes = {k: 0 for k in vaux}
+        if hoist:
+            aux_axes["fail_fire"] = None      # one flag for every lane
+        vstep = jax.vmap(lane_step, in_axes=(consts_axes, 0, aux_axes, 0))
 
         def cond(c):
-            i, (_s, _cache, done) = c
+            i, (_s, _cache, done) = c[:2]
             return (i < chunk_steps) & ~jnp.all(done)
 
         def body(c):
-            i, (s, cache, done) = c
-            s2, cache2, done2 = vstep(consts, pol, vaux, (s, cache))
+            i, (s, cache, done) = c[:2]
+            aux, fails = vaux, c[2:]
+            if hoist:
+                with jax.named_scope("chaos"):
+                    _, new_h, new_l = vrefresh(consts, s)
+                    fire = jnp.any((jnp.any(new_h, -1) | jnp.any(new_l, -1))
+                                   & ~done)
+                aux = {**vaux, "fail_fire": fire}
+                fails = (fails[0] + fire.astype(jnp.int32),)
+            s2, cache2, done2 = vstep(consts, pol, aux, (s, cache))
             # freeze the STATE of finished lanes (it is the result the
             # scheduler retires); the cache needs no select — it is never
             # read into results, a finished lane's pseudo-steps leave its
@@ -2205,9 +2259,11 @@ def make_fleet_chunk(meta, static_pol=None, chunk_steps: int = 32,
             s = jax.lax.cond(jnp.any(done),
                              lambda: tree_select(done, s, s2),
                              lambda: s2)
-            return i + 1, (s, cache2, done | done2)
+            return (i + 1, (s, cache2, done | done2)) + fails
 
-        return jax.lax.while_loop(cond, body, (0, carry))[1]
+        init = (0, carry) + ((jnp.int32(0),) if hoist else ())
+        i, carry, *fails = jax.lax.while_loop(cond, body, init)
+        return carry, jnp.stack([i, fails[0] if hoist else jnp.int32(0)])
 
     return chunk
 

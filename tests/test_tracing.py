@@ -282,10 +282,29 @@ def _compiled_loops():
     carry = jax.eval_shape(lambda c: engine.init_fleet_carry(c, meta, 2),
                            consts)
     fleet_hlo = jax.jit(chunk).lower(consts, lane, carry).compile().as_text()
+    # the same programs with failures on: the serial runner, and the
+    # chunk that decides the failure transitions' predicate outside its
+    # lane vmap
+    fexp = Experiment(scenarios=get_scenario(
+        "paper-fabric-failures", n_each=1, split=2, k_max=16),
+        policies=POLICIES)
+    fconsts, fmeta = fexp.build()
+    fn, init = runners._make_fn(fmeta, "single", counted=False)
+    pol = as_policy_arrays(PolicyConfig())
+    serial = jax.jit(fn).lower(fconsts, pol, jax.eval_shape(
+        init, fconsts, pol)).compile().as_text()
+    fchunk = engine.make_fleet_chunk(fmeta, {"routing": 1, "traffic": 0,
+                                             "placement": 0}, 8)
+    fcarry = jax.eval_shape(
+        lambda c: engine.init_fleet_carry(c, fmeta, 2), fconsts)
+    fleet_fail = jax.jit(fchunk).lower(fconsts, lane,
+                                       fcarry).compile().as_text()
+    freeze = "cond/branch_1_fun/jit(_where)/select_n"
     return {"policy_batch": (batch, None),
             "policy_batch_uniform": (uniform, None),
-            "fleet_chunk": (fleet_hlo,
-                            "cond/branch_1_fun/jit(_where)/select_n")}
+            "fleet_chunk": (fleet_hlo, freeze),
+            "serial_failures": (serial, None),
+            "fleet_chunk_failures": (fleet_fail, freeze)}
 
 
 @pytest.fixture(scope="module")
@@ -294,7 +313,7 @@ def compiled_loops():
 
 
 @pytest.mark.parametrize("program", ["policy_batch", "policy_batch_uniform",
-                                     "fleet_chunk"])
+                                     "fleet_chunk", "fleet_chunk_failures"])
 def test_every_loop_fusion_names_a_phase(compiled_loops, program):
     hlo, freeze = compiled_loops[program]
     fusions = _loop_fusions(hlo)
@@ -322,7 +341,7 @@ def test_uniform_eq3_batch_has_no_waterfill_loop(compiled_loops):
 
 
 @pytest.mark.parametrize("program", ["policy_batch", "policy_batch_uniform",
-                                     "fleet_chunk"])
+                                     "fleet_chunk", "fleet_chunk_failures"])
 def test_route_choice_sits_under_activate(compiled_loops, program):
     """Every operation of the route choice and route-link composition
     (``route_choice``) is nested in the ``activate`` phase, so the phase
@@ -336,6 +355,24 @@ def test_route_choice_sits_under_activate(compiled_loops, program):
         parts = n.split("/")
         at = next(i for i, p in enumerate(parts) if "route_choice" in p)
         assert any("activate" in p for p in parts[:at]), n
+
+
+@pytest.mark.parametrize("program, chaos", [
+    ("serial_failures", "chaos"), ("fleet_chunk_failures", r"vmap\(chaos\)")])
+def test_fail_transitions_sit_under_chaos(compiled_loops, program, chaos):
+    """The failure transitions (``fail_transitions``) are nested in the
+    ``chaos`` phase, in a conditional's branch: in the serial runner and
+    in the fleet chunk, whose predicate comes from outside its lane vmap
+    so the cond survives the vmap (``vmap(chaos)/cond``)."""
+    hlo, _ = compiled_loops[program]
+    names = set(re.findall(r'op_name="([^"]*)"', hlo))
+    fail = [n for n in names if "fail_transitions" in n]
+    assert fail
+    inside = re.compile(r"/" + chaos
+                        + r"/cond/branch_\d+_fun/fail_transitions/")
+    for n in fail:
+        assert op_phase(n) == "chaos", n
+        assert inside.search(n), n
 
 
 @pytest.mark.parametrize("k_max, truncated", [(8, 48), (16, 0)])
