@@ -35,12 +35,12 @@ def main(argv=None):  # jaxcheck: disable=naked-timer
 
     from . import (advisor_validation, chaos_sweep, ctrl_sweep,
                    engine_profile, failure_sweep, fig11_13_usecase,
-                   fleet_sweep, roofline_table, scenario_sweep,
-                   sim_throughput, stream_sweep)
+                   fleet_sweep, scenario_sweep, sim_throughput,
+                   stream_sweep)
 
     def banner(step, title):
         print("=" * 72)
-        print(f"[{step}/11] {title}")
+        print(f"[{step}/10] {title}")
         print("=" * 72)
 
     banner(1, "paper use-case (Figs. 11a/11b/12/13) — SDN vs legacy")
@@ -58,22 +58,19 @@ def main(argv=None):  # jaxcheck: disable=naked-timer
     json.dump(results["advisor"],
               open("experiments/advisor_validation.json", "w"), indent=1)
 
-    banner(4, "roofline table (aggregated from dry-run artifacts)")
-    results["roofline"] = roofline_table.main()
-
     # --- the post-seed sweep benches: quick = the CI bench-job grids,
     # --- full = the committed-baseline grids (each script's defaults)
-    banner(5, "scenario sweep (topology x placement grid)")
+    banner(4, "scenario sweep (topology x placement grid)")
     scenario_sweep.main(
         (["--scenarios", "paper-fabric", "leaf-spine"] if quick else [])
         + ["--json", "experiments/BENCH_scenario_sweep.json"])
 
-    banner(6, "failure sweep (failure-rate x routing grid)")
+    banner(5, "failure sweep (failure-rate x routing grid)")
     failure_sweep.main(
         (["--rates", "0", "3e-4", "--seeds", "1"] if quick else [])
         + ["--json", "experiments/BENCH_failure_sweep.json"])
 
-    banner(7, "control-plane sweep (install-latency x routing grid)")
+    banner(6, "control-plane sweep (install-latency x routing grid)")
     ctrl_sweep.main(
         (["--latencies", "0.005", "0.05"] if quick else [])
         + ["--json", "experiments/BENCH_ctrl.json"])
@@ -84,22 +81,22 @@ def main(argv=None):  # jaxcheck: disable=naked-timer
     # baseline with a mismatched grid
     suffix = ".ci.json" if quick else ".json"
 
-    banner(8, "fleet sweep (policy x failure-rate x seed cohorts)")
+    banner(7, "fleet sweep (policy x failure-rate x seed cohorts)")
     fleet_sweep.main(
         (["--sims", "1000"] if quick else [])
         + ["--json", f"experiments/BENCH_fleet{suffix}"])
 
-    banner(9, "engine step-kernel profile")
+    banner(8, "engine step-kernel profile")
     engine_profile.main(
         (["--iters", "1"] if quick else ["--iters", "3"])
         + ["--json", f"experiments/BENCH_engine{suffix}"])
 
-    banner(10, "streaming sweep (arrival rate x routing, slot ring)")
+    banner(9, "streaming sweep (arrival rate x routing, slot ring)")
     stream_sweep.main(
         (["--horizon", "400"] if quick else [])
         + ["--json", f"experiments/BENCH_stream{suffix}"])
 
-    banner(11, "chaos sweep (degradation severity x speculation grid)")
+    banner(10, "chaos sweep (degradation severity x speculation grid)")
     chaos_sweep.main(
         (["--severities", "0.2", "0.4", "--seeds", "1"] if quick else [])
         + ["--json", f"experiments/BENCH_chaos{suffix}"])

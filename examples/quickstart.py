@@ -1,5 +1,5 @@
 """Quickstart: the paper's experiment through the unified Experiment API
-(DESIGN.md §6) + a tiny LM train run.
+(DESIGN.md §6).
 
   PYTHONPATH=src python examples/quickstart.py
 """
@@ -12,7 +12,7 @@ from repro.util import enable_compile_cache
 
 enable_compile_cache()
 
-# --- 1. BigDataSDNSim: SDN vs legacy on the paper's fat-tree (Tables 2-3).
+# BigDataSDNSim: SDN vs legacy on the paper's fat-tree (Tables 2-3).
 # One declarative experiment; .run() compiles once and returns the grid.
 res = Experiment(
     scenarios=get_scenario("paper-fabric", n_each=5),   # the 15-job mix
@@ -26,27 +26,4 @@ for pi, (name, row) in enumerate(zip(res.policy_names, res.rows())):
           f"completion {row['mean_completion_s']:7.1f} s   "
           f"energy {row['energy_kwh']:6.2f} kWh")
 
-# --- 2. Train a small LM with the same repo's training stack
-import jax
-import jax.numpy as jnp
-
-from repro.configs import get_smoke_config
-from repro.data import TokenPipeline
-from repro.models import get_model
-from repro.train import AdamWConfig, make_train_step
-from repro.train import init as opt_init
-
-cfg = get_smoke_config("qwen3-4b")
-api = get_model(cfg)
-params = api.init(jax.random.PRNGKey(0))
-ocfg = AdamWConfig(total_steps=30, warmup_steps=3)
-opt = opt_init(ocfg, params)
-step = jax.jit(make_train_step(api, ocfg))
-pipe = TokenPipeline(vocab=cfg.vocab, batch=8, seq=32)
-for i in range(30):
-    batch = {k: jnp.asarray(v) for k, v in pipe.batch_at(i).items()}
-    params, opt, met = step(params, opt, batch)
-    if i % 10 == 0 or i == 29:
-        print(f"step {i:3d}  loss {float(met['loss']):.3f}  "
-              f"lr {float(met['lr']):.2e}")
 print("quickstart OK")

@@ -1,12 +1,4 @@
 from .advisor import Advice, advise_allreduce, analytic_time
-from .hlo import CollectiveStats, collective_stats, duplicate_fusion_count
 from .hw import V5E, HwSpec
-from .terms import (RooflineReport, analyze, analyze_raw,
-                    count_active_params, count_params, model_flops,
-                    peak_memory, raw_counts)
 
-__all__ = ["Advice", "advise_allreduce", "analytic_time",
-           "CollectiveStats", "collective_stats", "duplicate_fusion_count",
-           "V5E", "HwSpec", "RooflineReport", "analyze", "analyze_raw",
-           "raw_counts", "peak_memory",
-           "count_active_params", "count_params", "model_flops"]
+__all__ = ["Advice", "advise_allreduce", "analytic_time", "V5E", "HwSpec"]

@@ -1,25 +1,16 @@
 """Small shared utilities.
 
-``scan`` wraps ``jax.lax.scan`` with a process-wide UNROLL switch: XLA's
-cost analysis counts a while-loop body ONCE, so roofline-counting compiles
-run under ``unrolled_counting()`` which makes every repro scan fully
-unroll (depth-1/2 model variants keep the unrolled op count small).
-
 ``enable_compile_cache`` turns on JAX's persistent compilation cache; the
 entry points (``chip_smoke.py``, ``benchmarks/``, ``examples/``) call it
 first thing, importing the library never does.
 """
 from __future__ import annotations
 
-import contextlib
 import os
-import threading
 from pathlib import Path
 from typing import Optional
 
 import jax
-
-_state = threading.local()
 
 # fixed, so that one checkout's runs find each other's entries (the path
 # is part of the cache key); git-ignored
@@ -42,23 +33,3 @@ def enable_compile_cache() -> Optional[str]:
     compilation_cache.reset_cache()
     return path
 
-
-def _unroll() -> bool:
-    return getattr(_state, "unroll", False)
-
-
-@contextlib.contextmanager
-def unrolled_counting():
-    prev = getattr(_state, "unroll", False)
-    _state.unroll = True
-    try:
-        yield
-    finally:
-        _state.unroll = prev
-
-
-def scan(f, init, xs, length=None, unroll=None):
-    """jax.lax.scan that fully unrolls under ``unrolled_counting()``."""
-    if unroll is None:
-        unroll = True if _unroll() else 1
-    return jax.lax.scan(f, init, xs, length=length, unroll=unroll)

@@ -1,23 +1,15 @@
 import os
 import sys
 
-# smoke tests and benches must see ONE device — the 512-device flag is set
-# only inside launch/dryrun.py (per the assignment).
+# the tests run on the CPU unless the caller picks a platform
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-import dataclasses                                            # noqa: E402
-
-import numpy as np                                            # noqa: E402
 import pytest                                                 # noqa: E402
 
 
-# ---------------------------------------------------------------------------
-# shared fixtures & helpers (deduped from the per-file copies: test_failures,
-# test_api, test_topology_scenarios, test_engine_equiv all used private
-# variants of these)
-# ---------------------------------------------------------------------------
+# the plain helpers the test files share live in simhelpers.py
 
 
 @pytest.fixture(scope="session")
@@ -26,52 +18,3 @@ def mini_setup():
     from repro.core import build_setup, paper_cluster, paper_jobs
     return build_setup(paper_jobs(seed=0, n_each=1), paper_cluster(),
                        split=2)
-
-
-def with_failures(setup, sched):
-    """A copy of ``setup`` carrying the given FailureSchedule."""
-    return dataclasses.replace(setup, failures=sched)
-
-
-def with_ctrl(setup, cfg):
-    """A copy of ``setup`` carrying the given CtrlPlaneConfig."""
-    return dataclasses.replace(setup, ctrl=cfg)
-
-
-def with_degradation(setup, sched, spec_slots=None):
-    """A copy of ``setup`` carrying the given DegradationSchedule (and
-    optionally clone capacity for the speculation axis)."""
-    kw = {"degradation": sched}
-    if spec_slots is not None:
-        kw["spec_slots"] = spec_slots
-    return dataclasses.replace(setup, **kw)
-
-
-def dims(setup):
-    """-> (n_hosts, n_links) of the setup's topology (FailureSchedule
-    constructor args)."""
-    topo = setup.cluster.topo
-    return topo.n_hosts, topo.n_links
-
-
-def tiny_setups():
-    """Two tiny heterogeneous scenarios for packed-sweep tests."""
-    from repro.core.mapreduce import build_setup
-    from repro.core.topology import canonical_tree, leaf_spine
-    from repro.scenarios import make_cluster, uniform_workload, zipf_workload
-    ls = build_setup(uniform_workload(n_jobs=2, seed=0),
-                     make_cluster(leaf_spine(2, 2, 2)), k_max=4)
-    ct = build_setup(zipf_workload(n_jobs=3, seed=1),
-                     make_cluster(canonical_tree(2, 2, 2)), k_max=4)
-    return [("leaf-spine", ls), ("canon-tree", ct)]
-
-
-def assert_states_equal(a, b, label=""):
-    """Leaf-by-leaf bit equality (NaN == NaN) between two SimStates."""
-    for name in a._fields:
-        la = np.asarray(getattr(a, name))
-        lb = np.asarray(getattr(b, name))
-        assert la.shape == lb.shape, \
-            f"{label}: SimState.{name} shape {la.shape} != {lb.shape}"
-        assert np.array_equal(la, lb, equal_nan=True), \
-            f"{label}: SimState.{name} values differ"

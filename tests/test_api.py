@@ -10,8 +10,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import assert_states_equal as assert_states_identical
-from conftest import tiny_setups as _tiny_setups
+from simhelpers import assert_states_equal as assert_states_identical
+from simhelpers import tiny_setups as _tiny_setups
 from repro.api import (Experiment, PolicyConfig, SimMeta, as_policy_arrays,
                        policy_field_names, runners)
 from repro.core import (PLACE_RANDOM, ROUTE_LEGACY, ROUTE_SDN, paper_setup,

@@ -16,16 +16,14 @@ import dataclasses
 import numpy as np
 import pytest
 
-from conftest import (assert_states_equal, dims, with_ctrl, with_degradation,
-                      with_failures)
+from simhelpers import assert_states_equal, dims, with_ctrl, with_degradation
 from invariants import check_all, check_chaos, check_finite, check_stream
-from repro.core import (DegradationSchedule, PolicyConfig, host_crash,
-                        host_slowdown, link_brownout, no_degradation,
-                        no_failures, simulate)
+from repro.core import (PolicyConfig, host_crash, host_slowdown,
+                        link_brownout, no_degradation, no_failures, simulate)
 from repro.core.ctrlplane import CtrlPlaneConfig
 from repro.core.engine import make_consts
 from repro.core.flows import Flow, flows_setup
-from repro.core.mapreduce import DONE, build_setup
+from repro.core.mapreduce import build_setup
 from repro.core.policies import (PLACE_ROUND_ROBIN, ROUTE_SDN, SPEC_OFF,
                                  SPEC_ON)
 from repro.core.topology import leaf_spine, torus_2d

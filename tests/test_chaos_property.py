@@ -8,14 +8,13 @@ test in test_chaos.py: window placement must never leak into the trace
 (inert windows are masked out of ``deg_breaks``), so there is no
 "breakpoint at t but zero effect" drift either.
 """
-import numpy as np
 import pytest
 
 pytest.importorskip("hypothesis")
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from conftest import assert_states_equal, with_degradation
+from simhelpers import assert_states_equal, with_degradation
 from repro.core import (PolicyConfig, no_degradation, simulate)
 from repro.core.flows import Flow, flows_setup
 from repro.core.mapreduce import build_setup

@@ -4,7 +4,7 @@ proactive install overlap, and migrate-on-congestion."""
 import numpy as np
 import pytest
 
-from conftest import with_ctrl, with_failures, dims, assert_states_equal
+from simhelpers import with_ctrl, with_failures, dims, assert_states_equal
 from invariants import check_all
 from repro.core import (CtrlPlaneConfig, INSTALL_PROACTIVE, MIG_CONGESTION,
                         PolicyConfig, ROUTE_LEGACY, ROUTE_SDN, host_crash,

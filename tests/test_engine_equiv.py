@@ -18,10 +18,9 @@ import itertools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
-from conftest import assert_states_equal
+from simhelpers import assert_states_equal
 from repro.core import fairshare
 from repro.core.engine import (NODE_OFFSET, init_state_from_consts,
                                make_consts)

@@ -1,13 +1,13 @@
 """Failure & recovery subsystem (DESIGN.md §7): §4 invariants under
 failures, no-failure bit-identity, SDN-reroute vs legacy-pin semantics.
 
-``mini_setup`` / ``with_failures`` / ``dims`` live in conftest.py (shared
-with the invariant and control-plane suites)."""
+``mini_setup`` lives in conftest.py, ``with_failures`` / ``dims`` in
+simhelpers.py (shared with the invariant and control-plane suites)."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import dims, with_failures
+from simhelpers import dims, with_failures
 from repro.core import (PolicyConfig, RECOVERY_RESUME, ROUTE_LEGACY,
                         ROUTE_SDN, host_crash, link_cut, no_failures,
                         simulate, simulate_batch, summarize)

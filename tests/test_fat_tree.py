@@ -10,7 +10,7 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-from conftest import assert_states_equal
+from simhelpers import assert_states_equal
 from repro.api import Experiment, PolicyConfig
 from repro.core.routing import RouteTable, UNREACHABLE_HOPS
 from repro.scenarios import get_scenario

@@ -14,7 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import assert_states_equal, dims
+from simhelpers import assert_states_equal, dims
 from repro.api import Experiment, fleet
 from repro.core import engine
 from repro.core.failures import no_failures

@@ -1,14 +1,10 @@
-"""Flow frontend oracles + serve loop correctness."""
-import jax
-import numpy as np
+"""Flow frontend oracles (DESIGN.md §Beyond-paper): closed-form ring and
+contention times."""
 import pytest
 
-from repro.configs import get_smoke_config
 from repro.core import PolicyConfig, simulate
 from repro.core.flows import Flow, flows_setup
 from repro.core.topology import torus_2d
-from repro.models import get_model
-from repro.serve import Request, ServeLoop
 
 
 def test_ring_allreduce_closed_form():
@@ -32,38 +28,3 @@ def test_flows_contention_vs_diverse():
     t_dis = float(simulate(flows_setup(topo, disjoint),
                            PolicyConfig()).time)
     assert t_same == pytest.approx(4 * t_dis, rel=1e-3)
-
-
-def test_serve_loop_matches_uninterrupted_decode():
-    """ServeLoop (admission + slots) must produce the same greedy tokens
-    as a hand-rolled prefill+decode for each request."""
-    cfg = get_smoke_config("qwen3-4b")
-    api = get_model(cfg)
-    params = api.init(jax.random.PRNGKey(0))
-    rng = np.random.RandomState(1)
-    prompts = [rng.randint(1, cfg.vocab, 8).astype(np.int32)
-               for _ in range(3)]
-    max_new = 5
-
-    # oracle: one request at a time, batch 1
-    import jax.numpy as jnp
-    want = []
-    for pr in prompts:
-        cache = api.init_cache(1, 64)
-        pad = np.zeros((32,), np.int32)
-        pad[-len(pr):] = pr
-        logits, cache = api.prefill(params, {"tokens": jnp.asarray(pad[None])},
-                                    cache)
-        toks = [int(jnp.argmax(logits[0, -1]))]
-        for _ in range(max_new):
-            lg, cache = api.decode_step(
-                params, jnp.asarray([[toks[-1]]], jnp.int32), cache)
-            toks.append(int(jnp.argmax(lg[0, 0])))
-        want.append(toks[:max_new + 1])
-
-    loop = ServeLoop(api, params, slots=2, max_len=64, bucket=32)
-    for i, pr in enumerate(prompts):
-        loop.submit(Request(rid=i, prompt=pr, max_new=max_new))
-    results = {r.rid: r.tokens for r in loop.run()}
-    for i in range(3):
-        assert results[i] == want[i], (i, results[i], want[i])

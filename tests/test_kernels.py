@@ -1,13 +1,9 @@
-"""Pallas kernels vs pure-jnp oracles (interpret mode), shape/dtype sweeps."""
+"""The min-plus Pallas kernel vs pure-jnp oracles (interpret mode), shape sweeps."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.flash_attention.ops import flash_attention
-from repro.kernels.flash_attention.ref import naive_attention
-from repro.kernels.selective_scan.ops import selective_scan
-from repro.kernels.selective_scan.ref import selective_scan_ref
 from repro.kernels.tropical_apsp.kernel import minplus_matmul
 from repro.kernels.tropical_apsp.ops import apsp
 from repro.kernels.tropical_apsp.ref import apsp_ref, minplus_matmul_ref
@@ -40,44 +36,6 @@ def test_apsp_vs_ref(n, density, block):
     finite = np.isfinite(want)
     np.testing.assert_allclose(got[finite], want[finite], rtol=1e-5)
     assert np.all(got[~finite] > 1e30)
-
-
-@pytest.mark.parametrize("b,sq,skv,h,kv,dh,causal,dtype", [
-    (2, 64, 64, 4, 2, 32, True, jnp.float32),
-    (1, 100, 100, 4, 4, 16, True, jnp.float32),
-    (2, 1, 40, 4, 2, 16, False, jnp.float32),
-    (1, 128, 256, 8, 2, 64, True, jnp.float32),
-    (2, 64, 64, 4, 1, 128, True, jnp.bfloat16),
-    (1, 48, 48, 2, 2, 64, False, jnp.bfloat16),
-])
-def test_flash_attention_sweep(b, sq, skv, h, kv, dh, causal, dtype):
-    k1, k2, k3 = jax.random.split(KEY, 3)
-    q = jax.random.normal(k1, (b, sq, h, dh), dtype)
-    k = jax.random.normal(k2, (b, skv, kv, dh), dtype)
-    v = jax.random.normal(k3, (b, skv, kv, dh), dtype)
-    off = skv - sq if causal else 0
-    got = flash_attention(q, k, v, causal=causal, q_offset=off,
-                          bq=32, bk=32, interpret=True)
-    want = naive_attention(q, k, v, causal=causal, q_offset=off)
-    tol = 2e-5 if dtype == jnp.float32 else 2e-2
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32),
-                               rtol=tol, atol=tol)
-
-
-@pytest.mark.parametrize("b,s,d,n,chunk,bd", [
-    (2, 16, 8, 4, 8, 8), (1, 100, 32, 16, 32, 16), (2, 64, 300, 16, 16, 64),
-    (1, 33, 24, 8, 16, 24),
-])
-def test_selective_scan_sweep(b, s, d, n, chunk, bd):
-    k1, k2, k3 = jax.random.split(KEY, 3)
-    a = jax.random.uniform(k1, (b, s, d, n), jnp.float32, 0.5, 0.999)
-    bb = jax.random.normal(k2, (b, s, d, n), jnp.float32) * 0.1
-    c = jax.random.normal(k3, (b, s, n), jnp.float32)
-    got = selective_scan(a, bb, c, chunk=chunk, bd=bd, interpret=True)
-    want = selective_scan_ref(a, bb, c)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-4, atol=1e-5)
 
 
 def test_apsp_on_paper_topology():

@@ -6,8 +6,8 @@ and a batch whose every static field varies keeps the generic program."""
 import jax
 import pytest
 
-from conftest import assert_states_equal as assert_states_identical
-from conftest import tiny_setups
+from simhelpers import assert_states_equal as assert_states_identical
+from simhelpers import tiny_setups
 from repro.api import Experiment, PolicyConfig, runners
 from repro.core import (PLACE_LEAST_USED, PLACE_RANDOM, PLACE_ROUND_ROBIN,
                         ROUTE_LEGACY, ROUTE_SDN)

@@ -10,7 +10,7 @@ fixed slot capacity completes in bounded memory (slow-marked).
 import numpy as np
 import pytest
 
-from conftest import assert_states_equal
+from simhelpers import assert_states_equal
 from invariants import check_stream
 from repro.api import Experiment
 from repro.core.policies import (PLACE_ROUND_ROBIN, PolicyConfig,

@@ -3,7 +3,7 @@ packed multi-topology sweep (single-run equivalence + smoke)."""
 import numpy as np
 import pytest
 
-from conftest import tiny_setups as _tiny_setups
+from simhelpers import tiny_setups as _tiny_setups
 from repro.core import (PLACE_LEAST_USED, PLACE_RANDOM, PolicyConfig,
                         simulate)
 from repro.core.routing import build_route_table, hop_distances_np
