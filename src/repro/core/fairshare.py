@@ -13,9 +13,69 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.extend import core as jex_core
+from jax.interpreters import batching, mlir
+
+from ..kernels.eq3_bottleneck.ref import eq3_bottleneck_ref
 
 TRAFFIC_FAIRSHARE = 0  # paper Eq. 3
 TRAFFIC_WATERFILL = 1  # beyond-paper max-min fairness
+
+# Eq. 3 lookups lowered, by path (``lookup_counts``), and a trace-time
+# switch for tests: the kernel through the Pallas interpreter on any
+# platform (counted as ``kernel``)
+_LOOKUPS = {"kernel": 0, "gather": 0}
+_INTERPRET = False
+
+
+def _count(x, *, path):
+    _LOOKUPS[path] += 1
+    return x
+
+
+# identity on a lookup's result that counts its path where it is lowered
+# (or run eagerly): a program keeps only the branch of the platform it is
+# lowered for, so the count says which path the device runs
+_counted_p = jex_core.Primitive("eq3_lookup")
+_counted_p.def_impl(_count)
+_counted_p.def_abstract_eval(lambda x, *, path: x)
+batching.primitive_batchers[_counted_p] = lambda args, dims, *, path: (
+    _counted_p.bind(*args, path=path), dims[0])
+mlir.register_lowering(
+    _counted_p, lambda ctx, x, *, path: [_count(x, path=path)])
+
+
+def lookup_counts() -> dict:
+    """Eq. 3 lookups lowered since import, by path: ``kernel`` (the
+    VMEM-resident Pallas lookup, on the TPU) or ``gather``
+    (``share_l[link]``, every other platform)."""
+    return dict(_LOOKUPS)
+
+
+def _gather(share_l, route_links):
+    return _counted_p.bind(eq3_bottleneck_ref(share_l, route_links),
+                           path="gather")
+
+
+def _kernel(share_l, route_links, *, interpret=False):
+    # imported here: Pallas adds ~1 s to the import of the whole package
+    from ..kernels.eq3_bottleneck.ops import eq3_bottleneck
+    return _counted_p.bind(
+        eq3_bottleneck(share_l, route_links, interpret=interpret),
+        path="kernel")
+
+
+def bottleneck_share(share_l: jnp.ndarray,
+                     route_links: jnp.ndarray) -> jnp.ndarray:
+    """Each packet's smallest per-link share along its route, ``+inf``
+    for an empty route.  The TPU serves an element gather one element at
+    a time, so a program lowered for the TPU takes the Pallas kernel;
+    every other platform keeps the gather.  Both give the same f32 bits
+    (the same values are min-reduced)."""
+    if _INTERPRET:
+        return _kernel(share_l, route_links, interpret=True)
+    return jax.lax.platform_dependent(share_l, route_links, tpu=_kernel,
+                                      default=_gather)
 
 
 def channel_counts(route_links: jnp.ndarray, active: jnp.ndarray,
@@ -42,14 +102,11 @@ def eq3_rates(route_links: jnp.ndarray, active: jnp.ndarray,
     """
     if nc is None:
         nc = channel_counts(route_links, active, link_bw.shape[0])
-    valid = route_links >= 0
-    safe = jnp.maximum(route_links, 0)
-    # per-LINK share first (tiny link axis), then one gather onto the
+    # per-LINK share first (tiny link axis), then one lookup onto the
     # packet axis — same float op on the same operands as dividing after
-    # the gather, one packet-scale op cheaper (DESIGN.md §8)
+    # the lookup, one packet-scale op cheaper (DESIGN.md §8)
     share_l = link_bw / jnp.maximum(nc, 1).astype(link_bw.dtype)
-    share = jnp.where(valid, share_l[safe], jnp.inf)
-    bot = jnp.min(share, axis=-1)
+    bot = bottleneck_share(share_l, route_links)
     bot = jnp.where(jnp.isinf(bot), jnp.asarray(intra_bw, link_bw.dtype), bot)
     return jnp.where(active, bot, 0.0)
 

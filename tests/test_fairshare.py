@@ -10,9 +10,13 @@ pytest.importorskip("hypothesis")
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
+import jax
+
 from repro.core import (CtrlPlaneConfig, INSTALL_PROACTIVE, INSTALL_REACTIVE,
-                        PolicyConfig, simulate)
+                        PolicyConfig, fairshare, simulate)
 from repro.core.fairshare import eq3_rates, waterfill_rates
+from repro.kernels.eq3_bottleneck.ops import eq3_bottleneck
+from repro.kernels.eq3_bottleneck.ref import eq3_bottleneck_ref
 from repro.core.flows import Flow, flows_setup
 from repro.core.topology import leaf_spine
 
@@ -112,6 +116,81 @@ def test_capacity_invariant_both_policies_any_iter_cap(inst, n_iter):
                             jnp.asarray(bw), INTRA, n_iter=n_iter)):
         load = link_loads(routes, np.asarray(rates), bw.shape[0])
         assert np.all(load <= bw * (1 + 1e-3)), (load, bw)
+
+
+# ---------------------------------------------------------------------------
+# the Eq. 3 bottleneck kernel (the TPU's lookup) against the gather
+# ---------------------------------------------------------------------------
+
+
+def _eq3_instance(rng, n_links, n_pkts, values, active):
+    """Link bandwidths with dead links (0), channel counts, routes of 0-6
+    hops padded with -1 (empty routes included), and an active mask."""
+    if values == "range":     # every binade the three-way bf16 split carries
+        bw = rng.uniform(1, 2, n_links) * 2.0 ** rng.integers(-100, 120,
+                                                                n_links)
+    else:                     # what the fabrics hold: bits/s
+        bw = rng.choice([1e9, 4e9], n_links)
+    bw = bw.astype(np.float32)
+    bw[rng.random(n_links) < 0.1] = 0.0
+    nc = rng.integers(0, 40, n_links).astype(np.int32)
+    routes = rng.integers(0, n_links, (n_pkts, 6)).astype(np.int32)
+    routes[np.arange(6)[None, :] >= rng.integers(0, 7, n_pkts)[:, None]] = -1
+    act = (rng.random(n_pkts) < 0.7) if active else np.zeros(n_pkts, bool)
+    return bw, nc, routes, act
+
+
+def _eq3_path(interpret, bw, nc, routes, act, width):
+    """Eq. 3 through the interpreted kernel, or the CPU's own path (the
+    gather)."""
+    prev = fairshare._INTERPRET
+    fairshare._INTERPRET = interpret
+    try:
+        f = lambda b, n, r, a: eq3_rates(r, a, b, INTRA, nc=n)  # noqa: E731
+        if width is not None:
+            f = jax.vmap(f)
+        return np.asarray(jax.jit(f)(bw, nc, routes, act))
+    finally:
+        fairshare._INTERPRET = prev
+
+
+# the two configurations' link counts (130: paper-usecase, 6,146:
+# fat-tree-k16; neither a multiple of 128) at 6 hops, the packet axis cut
+# for time at 6,146 links; lane widths as the fleet and the policy batch
+# vmap them
+@pytest.mark.parametrize("n_links,n_pkts,width,values,active", [
+    (130, 460, None, "engine", True),
+    (130, 460, 1, "range", True),
+    (130, 460, 2, "engine", True),
+    (130, 460, 16, "engine", True),
+    (130, 460, 2, "engine", False),
+    (6146, 300, None, "range", True),
+    (6146, 300, 2, "engine", True),
+    (128, 129, 2, "range", True),
+])
+def test_eq3_kernel_bit_identical_to_gather(n_links, n_pkts, width, values,
+                                            active):
+    rng = np.random.default_rng(n_links + n_pkts + (width or 0))
+    lanes = [_eq3_instance(rng, n_links, n_pkts, values, active)
+             for _ in range(width or 1)]
+    args = [np.stack(a) if width is not None else a[0]
+            for a in zip(*lanes)]
+    before = fairshare.lookup_counts()
+    got = _eq3_path(True, *args, width)
+    want = _eq3_path(False, *args, width)
+    after = fairshare.lookup_counts()
+    assert after["kernel"] == before["kernel"] + 1
+    assert after["gather"] == before["gather"] + 1
+    assert np.array_equal(got.view(np.int32), want.view(np.int32))
+    assert np.all(want[~args[3]] == 0)
+    # the lookup alone (lane 0): +inf exactly where a route is empty
+    bw, nc, routes, _ = lanes[0]
+    share = bw / np.maximum(nc, 1).astype(np.float32)
+    look = np.asarray(eq3_bottleneck(share, routes, interpret=True))
+    ref = np.asarray(eq3_bottleneck_ref(share, routes))
+    assert np.array_equal(look.view(np.int32), ref.view(np.int32))
+    empty = np.all(routes < 0, axis=-1)
+    assert empty.any() and np.all(np.isinf(look[empty]))
 
 
 # ---------------------------------------------------------------------------

@@ -93,6 +93,37 @@ def test_fleet_sharded_matches_serial():
     assert stats.devices == n_dev
 
 
+@pytest.mark.parametrize("name,overrides", [
+    ("paper-fabric", {}),
+    ("al-fares-fat-tree", {"k": 4, "n_each": 1, "k_max": 4}),
+])
+def test_fleet_identical_through_the_eq3_kernel(name, overrides):
+    """The TPU's Eq. 3 lookup (the Pallas kernel, here through its
+    interpreter) against the gather every other backend runs: the same
+    campaign, SDN and legacy cohorts, ends in bit-identical states, and
+    ``lookup_counts`` counts the path each program was lowered with."""
+    from repro.core import fairshare
+    from repro.scenarios import get_scenario
+    exp = Experiment(scenarios=get_scenario(name, **overrides).build(),
+                     policies=POLICIES[::2], seeds=(0, 1))
+    got = {}
+    try:
+        for path, interpret in (("gather", False), ("kernel", True)):
+            fairshare._INTERPRET = interpret
+            runners.cache_clear()
+            before = fairshare.lookup_counts()
+            got[path] = exp.run_fleet(width=2, chunk_steps=16)
+            after = fairshare.lookup_counts()
+            assert after[path] > before[path]
+            assert sum(after.values()) - sum(before.values()) \
+                == after[path] - before[path]
+    finally:
+        fairshare._INTERPRET = False
+        runners.cache_clear()
+    assert_results_identical(got["gather"], got["kernel"], f"{name}: ")
+    assert not np.asarray(got["gather"].states.stalled).any()
+
+
 @pytest.mark.slow
 def test_fleet_identical_xl():
     """leaf-spine-xl (the 128-host tier) through the fleet batch path."""
